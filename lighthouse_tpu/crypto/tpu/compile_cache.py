@@ -317,12 +317,21 @@ def _host_fingerprint():
 
 
 def _default_cache_dir():
-    env = os.environ.get("LTPU_COMPILE_CACHE_DIR")
-    if env:
-        return env
-    repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__)))))
-    return os.path.join(repo_root, ".compile_cache")
+    from ...utils.xla_cache import aot_cache_dir
+
+    return aot_cache_dir()
+
+
+def _execution_devices(args):
+    """The devices a program over `args` was compiled for: the mesh of a
+    sharded leaf, else the default device.  A deserialized executable
+    must load onto exactly these — left to itself it loads onto every
+    local device and then refuses 1-device arguments."""
+    for a in jax.tree_util.tree_leaves(args):
+        mesh = getattr(getattr(a, "sharding", None), "mesh", None)
+        if mesh is not None and mesh.size > 1:
+            return list(mesh.devices.flat)
+    return jax.devices()[:1]
 
 
 def _leaf_sharding_tag(a):
@@ -403,9 +412,10 @@ class CompileCache:
 
         return self._fingerprint + "-" + _sharding.topology_fingerprint()
 
-    def _entry_path(self, name, shape_hash):
+    def _entry_path(self, name, shape_hash, fingerprint=None):
+        fingerprint = fingerprint or self.fingerprint()
         return os.path.join(
-            self.cache_dir, f"{name}-{shape_hash}-{self.fingerprint()}.aot"
+            self.cache_dir, f"{name}-{shape_hash}-{fingerprint}.aot"
         )
 
     # -- core ---------------------------------------------------------
@@ -485,10 +495,16 @@ class CompileCache:
         return "scalar"
 
     def _load_from_disk(self, name, fn, args, shape_hash, label):
-        """(callable, 'deserialized'|'compiled'|'jit', ms)."""
-        path = self._entry_path(name, shape_hash)
+        """(callable, 'deserialized'|'compiled'|'jit', ms).
+
+        The fingerprint is read once, here: its topology part follows the
+        live mesh env, which may change while this program compiles, and
+        the entry's file name and its blob must carry the same key."""
+        fingerprint = self.fingerprint()
+        path = self._entry_path(name, shape_hash, fingerprint)
+        devices = _execution_devices(args)
         if self.enabled:
-            exe, ms = self._try_deserialize(path)
+            exe, ms = self._try_deserialize(path, devices, fingerprint)
             if exe is not None:
                 with self._lock:
                     self.hits += 1
@@ -503,7 +519,8 @@ class CompileCache:
         ms = (time.monotonic() - t0) * 1e3
         COMPILE_MS.with_labels(name, label).set(round(ms, 1))
         if self.enabled:
-            self._try_serialize(path, compiled, name, shape_hash)
+            self._try_serialize(path, compiled, name, shape_hash, devices,
+                                fingerprint)
         return compiled, "compiled", ms
 
     @staticmethod
@@ -516,14 +533,12 @@ class CompileCache:
         load).  Only genuinely-compiled executables round-trip, so
         canonical kernels always compile for real — this AOT cache is
         their persistence tier."""
-        try:
-            from jax._src.config import enable_compilation_cache
-        except Exception:                         # jax moved the knob
-            return jax.jit(fn).lower(*args).compile()
+        from jax._src.config import enable_compilation_cache
+
         with enable_compilation_cache(False):
             return jax.jit(fn).lower(*args).compile()
 
-    def _try_deserialize(self, path):
+    def _try_deserialize(self, path, devices, fingerprint):
         from jax.experimental import serialize_executable as se
 
         if not os.path.exists(path):
@@ -532,10 +547,11 @@ class CompileCache:
         try:
             with open(path, "rb") as f:
                 blob = pickle.load(f)
-            if blob.get("fingerprint") != self.fingerprint():
+            if blob.get("fingerprint") != fingerprint:
                 raise ValueError("fingerprint mismatch")
             exe = se.deserialize_and_load(
-                blob["payload"], blob["in_tree"], blob["out_tree"]
+                blob["payload"], blob["in_tree"], blob["out_tree"],
+                execution_devices=devices,
             )
             return exe, (time.monotonic() - t0) * 1e3
         except Exception as e:
@@ -552,7 +568,8 @@ class CompileCache:
                 pass
             return None, 0.0
 
-    def _try_serialize(self, path, compiled, name, shape_hash):
+    def _try_serialize(self, path, compiled, name, shape_hash, devices,
+                       fingerprint):
         from jax.experimental import serialize_executable as se
 
         try:
@@ -561,9 +578,11 @@ class CompileCache:
             # (e.g. serialized from an executable some other cache layer
             # deserialized) must never reach disk, where it would poison
             # every later start with a deserialize-fail-recompile loop
-            se.deserialize_and_load(payload, in_tree, out_tree)
+            se.deserialize_and_load(
+                payload, in_tree, out_tree, execution_devices=devices
+            )
             blob = pickle.dumps({
-                "fingerprint": self.fingerprint(),
+                "fingerprint": fingerprint,
                 "kernel": name,
                 "payload": payload,
                 "in_tree": in_tree,
@@ -599,6 +618,21 @@ class CompileCache:
                         os.unlink(os.path.join(self.cache_dir, n))
                     except OSError:
                         pass
+        except OSError:
+            pass
+
+    def evict(self, name, args):
+        """Forget one program in memory and on disk, counted as a failed
+        deserialization: an XLA:CPU artifact can load and still lack a
+        kernel function it shared with other programs in the process
+        that wrote it — its first run then fails with NOT_FOUND."""
+        _, shape_hash = self._key(name, args)
+        with self._lock:
+            self._mem.pop((name, shape_hash), None)
+            self.deserialize_failures += 1
+        DESERIALIZE_FAILURES.inc()
+        try:
+            os.unlink(self._entry_path(name, shape_hash))
         except OSError:
             pass
 
@@ -691,7 +725,23 @@ class CachedKernel:
         # degrade to plain jit — a device fault during execution must
         # propagate to the circuit-breaker seam immediately, not
         # trigger a blocking inline recompile on the dispatch path
-        return self._timed(exe, args, "aot")
+        try:
+            return self._timed(exe, args, "aot")
+        except jax.errors.JaxRuntimeError as e:
+            # the one exception, on XLA:CPU only: a loaded artifact
+            # missing a kernel function (CompileCache.evict) is replaced
+            # by a compile.  On an accelerator every execution error
+            # reaches the breaker as it is.
+            msg = str(e)
+            if (jax.default_backend() != "cpu"
+                    or not msg.startswith("NOT_FOUND")
+                    or "not found" not in msg):
+                raise
+            log.warning("compile-cache entry for %s cannot run (%s); "
+                        "recompiling", self.name, msg[:120])
+            cache.evict(self.name, args)
+            exe = cache.load_or_compile(self.name, self.fn, args)
+            return self._timed(exe, args, "aot")
 
     def _timed(self, runner, args, source):
         """Execute and feed the profile registry: wall time around the
@@ -699,11 +749,7 @@ class CachedKernel:
         wall rather than async-dispatch wall.  Profiling failures never
         fail a launch — the result is already in hand."""
         t0 = time.monotonic()
-        out = runner(*args)
-        try:
-            jax.block_until_ready(out)
-        except Exception:
-            pass                     # non-array outputs: dispatch wall
+        out = jax.block_until_ready(runner(*args))
         wall = time.monotonic() - t0
         try:
             from . import profile
@@ -733,26 +779,34 @@ def prewarm(shapes=None, progress=None, cache=None, per_set=True):
     """
     from . import bls
 
-    cache = cache or get_cache()
-    planner = get_planner()
-    shapes = list(shapes or planner.prewarm_menu)
+    shapes = list(shapes or get_planner().prewarm_menu)
     specs = []
     for n, m in shapes:
         specs.extend(bls.kernel_specs(n, m, per_set=per_set))
-    # compile MISSING entries before deserializing present ones: on
-    # this jaxlib, an XLA:CPU executable compiled AFTER any
-    # deserialization in the same process serializes incompletely
-    # (`Symbols not found` at the publish-time round-trip proof), so a
-    # mixed menu would never grow the cache.  Missing-first keeps the
-    # publish window pristine; the hits still all land.
-    specs.sort(key=lambda s: cache.entry_on_disk(s[0], s[2]))
+    return {
+        "shapes": [f"{n}x{m}" for n, m in shapes],
+        **load_programs(specs, progress=progress, cache=cache),
+    }
+
+
+def load_programs(specs, progress=None, cache=None):
+    """Load-or-compile every (name, fn, example_args, label) spec.
+
+    Compiles MISSING entries before deserializing present ones: on
+    this jaxlib, an XLA:CPU executable compiled AFTER any
+    deserialization in the same process serializes incompletely
+    (`Symbols not found` at the publish-time round-trip proof), so a
+    mixed menu would never grow the cache.  Missing-first keeps the
+    publish window pristine; the hits still all land."""
+    cache = cache or get_cache()
+    specs = sorted(specs, key=lambda s: cache.entry_on_disk(s[0], s[2]))
     t0 = time.monotonic()
     hits0, misses0 = cache.hits, cache.misses
-    results = []
+    done = []
     for i, (name, fn, args, label) in enumerate(specs):
         t1 = time.monotonic()
         cache.load_or_compile(name, fn, args, shape_label=label)
-        results.append({
+        done.append({
             "kernel": name, "shape": label,
             "s": round(time.monotonic() - t1, 3),
         })
@@ -765,11 +819,10 @@ def prewarm(shapes=None, progress=None, cache=None, per_set=True):
     misses = cache.misses - misses0
     total = hits + misses
     return {
-        "shapes": [f"{n}x{m}" for n, m in shapes],
         "programs": len(specs),
         "cache_hits": hits,
         "cache_misses": misses,
         "cache_hit_rate": round(hits / total, 4) if total else 1.0,
         "wall_s": round(time.monotonic() - t0, 3),
-        "programs_detail": results,
+        "programs_detail": done,
     }

@@ -23,8 +23,8 @@ Why this shape:
     a 48-step `lax.scan` carry/borrow chain per call).  `mont_mul` costs
     three shift-formulation column products, two fold passes and ONE
     carry scan.  XLA compile time for the pairing graph is linear in
-    per-field-op HLO cost (ROUND3_NOTES), so this representation is the
-    second half of the compile-cliff fix — and removes ~10^2 sequential
+    per-field-op HLO cost (measured in round 3), so this representation is
+    the second half of the compile-cliff fix — and removes ~10^2 sequential
     48-step loops per curve op at RUNTIME, which is what the TPU VPU
     actually cares about.
 
@@ -260,8 +260,8 @@ compress = _compress_limbs
 
 def _mul_cols_shift(a, b, n_out=2 * NLIMB):
     """Schoolbook column sums via diagonal-sum reshape — no einsum, no
-    big constants (~8 elementwise HLO ops; the compile-cliff fix, see
-    ROUND3_NOTES).  cols[k] = sum_{i+j=k} a_i*b_j computed as diagonal
+    big constants (~8 elementwise HLO ops; the compile-cliff fix of
+    round 3).  cols[k] = sum_{i+j=k} a_i*b_j computed as diagonal
     sums of the flipped outer product through a (rows, L) -> (rows, L+1)
     flat reshape that shifts row i left by i.  Signed inputs are fine:
     f32 is exact for |products| < 2^24 and our |a_i|,|b_j| <= ~600.

@@ -6,9 +6,10 @@ of it, but every stage still round-trips intermediates at the fusion
 boundaries.  This kernel runs the WHOLE lazy-domain SOS Montgomery
 multiply — both limb-product contractions, the Montgomery-quotient
 contraction, the value-preserving input compressions, and the final carry
-propagation — as ONE `pallas_call` per batch tile: operands land in VMEM
-once, the three contractions hit the MXU back-to-back, and only the
-reduced result returns to HBM (pallas_guide.md: HBM->VMEM->compute).
+propagation (statically unrolled) — as ONE `pallas_call` per batch tile:
+operands land in VMEM once, the three contractions hit the MXU
+back-to-back, and only the reduced result returns to HBM (pallas_guide.md:
+HBM->VMEM->compute).
 
 It is a bit-for-bit mirror of `fp.mont_mul` on the lazy representation
 (49 signed int32 limbs, R = 2^392, fp.py module docstring).  The fold
@@ -24,8 +25,9 @@ small, which is irrelevant within a single fused kernel) — exact, so
 bit-identity still holds.  The f32 exactness argument is fp.py's:
 compressed limbs <= ~260, products < 2^18, 49-term sums < 2^24.
 
-Status: correctness-verified in interpreter mode; opt-in on hardware via
-bench.py's kernel candidates until profiled.
+Status: bit-identical to `fp.mont_mul` in interpreter mode, and lowers
+for v5e through Mosaic (tests/test_tpu_compile.py); never run on a chip.
+Opt-in via bench.py's kernel candidates until profiled.
 """
 
 import numpy as np
@@ -51,7 +53,7 @@ MASK = int(fp.MASK)
 def _mont_body(a, b, d2n, dn, npl, pconst, r392c, r400c):
     """The fused SOS Montgomery multiply on plain arrays (N, T) — shared
     by the one-shot kernel and the CHAIN kernel (state held in VMEM
-    across iterations; the TPU_BOUND.md byte-wall experiment)."""
+    across iterations; the byte-wall experiment)."""
     r392 = r392c[:, None]
     r400 = r400c[:, None]
 
@@ -61,7 +63,7 @@ def _mont_body(a, b, d2n, dn, npl, pconst, r392c, r400c):
     def fold_w(c):
         lo = c & MASK
         hi = c >> LB
-        return lo + jnp.concatenate([z1, hi[:-1]], axis=0) + hi[-1][None] * r392
+        return lo + jnp.concatenate([z1, hi[:-1]], axis=0) + hi[-1:] * r392
 
     def fold3_w(c):
         b0 = c & MASK
@@ -72,8 +74,10 @@ def _mont_body(a, b, d2n, dn, npl, pconst, r392c, r400c):
             + jnp.concatenate([z1, b1[:-1]], axis=0)
             + jnp.concatenate([z2, b2[:-2]], axis=0)
         )
-        spill392 = b1[-1] + b2[-2]
-        return out + spill392[None] * r392 + b2[-1][None] * r400
+        # (1, T) row slices, never int indices: Mosaic has no
+        # dynamic_slice, which an int index lowers to
+        spill392 = b1[-1:] + b2[-2:-1]
+        return out + spill392 * r392 + b2[-1:] * r400
 
     def compress(c):
         return fold_w(fold_w(fold3_w(c)))
@@ -110,16 +114,17 @@ def _mont_body(a, b, d2n, dn, npl, pconst, r392c, r400c):
     p_f = jnp.broadcast_to(pconst.astype(jnp.float32)[:, None], a.shape)
     u = cols(m_red.astype(jnp.float32), p_f, d2n).astype(jnp.int32) + cols_t
 
-    def carry_step(carry, col):
-        t = col + carry
-        return t >> LB, t & MASK
-
-    carry, limbs = lax.scan(
-        carry_step, jnp.zeros((u.shape[1],), jnp.int32), u
-    )
-    res = limbs[NLIMB:]                                   # (N, T) = u / R
-    top = res[-1] + carry * (1 << LB)
-    return jnp.concatenate([res[:-1], top[None]], axis=0)
+    # carry propagation over the 2N columns, unrolled statically: Mosaic
+    # lowers static row slices, not the dynamic_slice a lax.scan emits
+    carry = z1
+    res = []
+    for i in range(2 * NLIMB):
+        t = u[i:i + 1] + carry
+        carry = t >> LB
+        if i >= NLIMB:
+            res.append(t & MASK)                          # u / R rows
+    res[-1] = res[-1] + carry * (1 << LB)
+    return jnp.concatenate(res, axis=0)
 
 
 def _mont_mul_kernel(
@@ -204,7 +209,7 @@ def mont_mul_pallas(a, b, interpret=False):
 def mont_chain_pallas(a, b, steps, interpret=False):
     """x <- mont_mul(x, b), `steps` times, as ONE pallas_call: the chain
     state never leaves VMEM between iterations.  This is the byte-wall
-    experiment from TPU_BOUND.md — against `mont_chain_xla` (same chain
+    experiment — against `mont_chain_xla` (same chain
     as `steps` separate XLA ops, HBM round-trip per step) the ratio
     directly measures what pairing-layer fusion buys."""
     from jax.experimental import pallas as pl

@@ -3,7 +3,7 @@ choice integration, attestation batches with poisoning fallback, store
 persistence and restart.
 
 Mirrors /root/reference/beacon_node/beacon_chain/src/block_verification.rs
-and attestation_verification/batch.rs semantics (VERDICT item 4).
+and attestation_verification/batch.rs semantics.
 """
 
 import os

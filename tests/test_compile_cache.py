@@ -498,3 +498,93 @@ def test_compile_bench_tool_records_speedup(tmp_path, monkeypatch):
     )
     assert summary["warm_start_speedup"] is None or summary["warm_start_speedup"] >= 1.0
     assert summary["programs"] == 2
+
+
+def test_cache_dirs_follow_jax_compilation_cache_dir(tmp_path, monkeypatch):
+    """One rule places both caches: with JAX_COMPILATION_CACHE_DIR set,
+    JAX's persistent cache is that directory (the repo never overrides
+    it) and the AOT cache is a subdirectory of it; unset, both are
+    fixed paths in the checkout."""
+    import jax
+
+    from lighthouse_tpu.utils import xla_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert xla_cache.cache_dir() == str(tmp_path)
+    aot = cc.CompileCache().cache_dir
+    assert os.path.dirname(aot) == str(tmp_path)
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.append(name))
+    xla_cache.configure()
+    assert "jax_compilation_cache_dir" not in updates
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert xla_cache.cache_dir() == xla_cache.cache_dir()
+    assert os.path.dirname(xla_cache.cache_dir()) == os.path.join(
+        repo, ".xla_cache")
+    assert cc.CompileCache().cache_dir == os.path.join(repo, ".compile_cache")
+    xla_cache.configure()
+    assert "jax_compilation_cache_dir" in updates
+
+
+_MISSING_FN = "NOT_FOUND: Buffer Definition Event: Function fusion.1 not found"
+
+
+@pytest.mark.parametrize("backend, error, healed", [
+    ("cpu", _MISSING_FN, True),
+    ("cpu", "INTERNAL: device halted", False),
+    ("tpu", _MISSING_FN, False),
+])
+def test_unrunnable_artifact_is_recompiled_device_errors_propagate(
+        tmp_path, monkeypatch, backend, error, healed):
+    """An artifact that loads but lacks a kernel function (XLA:CPU can
+    serialize one that way) is evicted from memory and disk and compiled
+    once; any other execution error, and any error at all on an
+    accelerator, reaches the caller untouched."""
+    import jax
+
+    cache = cc.CompileCache(cache_dir=str(tmp_path), enabled=True)
+    monkeypatch.setattr(cc, "_CACHE", cache)
+    x = jnp.arange(12, dtype=jnp.int32).reshape(3, 4)
+    cache.load_or_compile("toy_heal", _toy_kernel, (x,))
+    assert cache.entry_on_disk("toy_heal", (x,))
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+
+    def unrunnable(*args):
+        raise jax.errors.JaxRuntimeError(error)
+
+    _, shape_hash = cache._key("toy_heal", (x,))
+    cache._mem[("toy_heal", shape_hash)] = unrunnable
+    kernel = cc.CachedKernel("toy_heal", _toy_kernel)
+    if healed:
+        total, _ = kernel(x)
+        assert int(total) == int((x * 2 + 1).sum())
+        assert cache.deserialize_failures == 1
+        assert cache.misses == 2            # the first compile + the heal
+    else:
+        with pytest.raises(jax.errors.JaxRuntimeError, match=error[:8]):
+            kernel(x)
+        assert cache.deserialize_failures == 0
+        assert cache.entry_on_disk("toy_heal", (x,))
+
+
+def test_entry_keeps_the_fingerprint_its_load_began_with(tmp_path,
+                                                         monkeypatch):
+    """The mesh env may change while a program compiles (chip_smoke's
+    four-chip legs): the entry's file name and its blob both carry the
+    fingerprint read when the load began, so the next process under that
+    plan loads it instead of discarding it."""
+    from lighthouse_tpu.crypto.tpu import sharding
+
+    tags = iter(["d4dp4mp1"] + ["d4dp1mp1"] * 100)
+    monkeypatch.setattr(sharding, "topology_fingerprint", lambda: next(tags))
+    x = jnp.arange(12, dtype=jnp.int32).reshape(3, 4)
+    cc.CompileCache(cache_dir=str(tmp_path), enabled=True).load_or_compile(
+        "toy_fp", _toy_kernel, (x,))
+
+    monkeypatch.setattr(sharding, "topology_fingerprint", lambda: "d4dp4mp1")
+    again = cc.CompileCache(cache_dir=str(tmp_path), enabled=True)
+    again.load_or_compile("toy_fp", _toy_kernel, (x,))
+    assert (again.hits, again.deserialize_failures) == (1, 0)

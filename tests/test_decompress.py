@@ -4,7 +4,7 @@ both sign bits, infinity, and every rejection class."""
 import numpy as np
 import pytest
 
-# slow-marked while the compile-cliff work lands (ROUND3_NOTES): the
+# slow-marked while the compile-cliff work lands: the
 # decompress kernel's cold compile dominates the whole fast lane on a
 # machine whose XLA cache is empty.  Un-mark once cold compile is
 # back under ~1 minute.

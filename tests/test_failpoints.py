@@ -57,7 +57,7 @@ class RecordingDevice:
         sets = list(sets)
         self.batches.append(len(sets))
         if self.broken and self.on_device_fallback is not None:
-            self.on_device_fallback(RuntimeError("device tunnel dead"))
+            self.on_device_fallback(RuntimeError("device error"))
         return True
 
     def verify_signature_sets_per_set(self, sets, priority=None):

@@ -1,4 +1,4 @@
-"""Independent conformance checks (VERDICT item 6).
+"""Independent conformance checks.
 
 The EF `bls12-381-tests` vectors cannot be fetched (zero egress) and no
 copy exists in the image, so independence comes from three sources that do

@@ -51,7 +51,7 @@ class BrokenDeviceVerifier(StubVerifier):
 
     def verify_signature_sets(self, sets, priority=None):
         if self.on_device_fallback is not None:
-            self.on_device_fallback(RuntimeError("device tunnel dead"))
+            self.on_device_fallback(RuntimeError("device error"))
         return super().verify_signature_sets(sets, priority)
 
 

@@ -48,7 +48,7 @@ def test_pallas_handles_edge_values():
 
 def test_mont_chain_pallas_matches_xla():
     """The fused chain kernel (state in VMEM across iterations) is
-    bit-identical to the XLA op-per-step chain (TPU_BOUND.md experiment
+    bit-identical to the XLA op-per-step chain (the byte-wall experiment
     machinery must be trustworthy before its ratio means anything)."""
     import jax.numpy as jnp
 

@@ -185,7 +185,7 @@ class FlakyDeviceVerifier(StubVerifier):
     def verify_signature_sets(self, sets, priority=None):
         self.calls += 1
         if self.broken and self.on_device_fallback is not None:
-            self.on_device_fallback(RuntimeError("device tunnel dead"))
+            self.on_device_fallback(RuntimeError("device error"))
         return super().verify_signature_sets(sets, priority)
 
 

@@ -54,10 +54,10 @@ def run_prewarm(shapes, cache_dir):
 
 
 def cached_start_subprocess(shapes, cache_dir, timeout=1800):
-    """Measure a SECOND-process prewarm against a populated cache dir."""
+    """Measure a SECOND-process prewarm against a populated cache dir
+    (CPU only: the child is pinned there)."""
     spec = ",".join(f"{n}x{m}" for n, m in shapes)
-    env = dict(os.environ)
-    env["LTPU_COMPILE_CACHE_DIR"] = cache_dir
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, os.path.abspath(__file__),
          "--load-only", "--shapes", spec, "--cache-dir", cache_dir],
@@ -138,7 +138,14 @@ def main():
         print(json.dumps(summary))
         return 0
 
-    summary = bench_shapes(shapes, cache_dir=args.cache_dir)
+    import jax
+
+    # a child cannot reach a chip this process holds: off the CPU the
+    # cached start runs in-process against a fresh CompileCache
+    summary = bench_shapes(
+        shapes, cache_dir=args.cache_dir,
+        subprocess_load=jax.devices()[0].platform == "cpu",
+    )
     line = json.dumps(summary)
     print(line)
     if args.json:

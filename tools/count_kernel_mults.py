@@ -4,7 +4,7 @@
 Traces batched_verify_kernel on CPU with fp.mont_mul wrapped by a
 counter: every call records (instances, lane-weighted mults), giving the
 M in the roofline bound  sets/s <= T_mult(B_eff) / M_per_set
-(TPU_BOUND.md; judge r5 item 1c).  Pure host-side tracing — no TPU.
+(ROADMAP.md's reachability bound).  Pure host-side tracing — no TPU.
 
 Usage: python tools/count_kernel_mults.py [sets pks]...
 """
