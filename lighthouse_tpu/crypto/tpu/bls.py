@@ -35,6 +35,7 @@ import secrets
 import threading as _threading
 import time as _time
 from collections import OrderedDict
+from contextlib import contextmanager as _contextmanager
 
 import numpy as np
 import jax
@@ -276,35 +277,48 @@ def batched_verify_kernel(pk, sig, u0, u1, rands):
     u0, u1: Fp2 hash-to-field outputs, leaves (24, n)
     rands: (2, n) uint32 blinding scalars
     Returns a scalar bool.
+
+    Each stage runs under a `jax.named_scope`, so its device ops carry
+    the stage's name in a profile.
     """
-    agg = cv.point_tree_sum(cv.FP_OPS, pk, axis=-1)          # (24, n)
-    sub_ok = jnp.all(cv.g2_in_subgroup(sig))
-    h = h2c.hash_to_g2_device(u0, u1)
+    with jax.named_scope("pubkey_tree_sum"):
+        agg = cv.point_tree_sum(cv.FP_OPS, pk, axis=-1)      # (24, n)
+    with jax.named_scope("g2_subgroup_check"):
+        sub_ok = jnp.all(cv.g2_in_subgroup(sig))
+    with jax.named_scope("hash_to_g2"):
+        h = h2c.hash_to_g2_device(u0, u1)
 
-    agg_r = cv.mul_u64(cv.FP_OPS, agg, rands)
-    sig_r = cv.mul_u64(cv.F2_OPS, sig, rands)
-    sig_acc = cv.point_tree_sum(cv.F2_OPS, sig_r, axis=-1)
-    sig_acc = jax.tree_util.tree_map(lambda x: x[..., None], sig_acc)
+    with jax.named_scope("blinding_ladders"):
+        agg_r = cv.mul_u64(cv.FP_OPS, agg, rands)
+        sig_r = cv.mul_u64(cv.F2_OPS, sig, rands)
+    with jax.named_scope("signature_tree_sum"):
+        sig_acc = cv.point_tree_sum(cv.F2_OPS, sig_r, axis=-1)
+        sig_acc = jax.tree_util.tree_map(lambda x: x[..., None], sig_acc)
 
-    # masks from Jacobian Z before affine flattening
-    g1_inf = cv.is_inf(cv.FP_OPS, agg_r) | cv.is_inf(cv.F2_OPS, h)
-    acc_inf = cv.is_inf(cv.F2_OPS, sig_acc)
-    mask = jnp.concatenate([~g1_inf, ~acc_inf], axis=0)
+    with jax.named_scope("to_affine"):
+        # masks from Jacobian Z before affine flattening
+        g1_inf = cv.is_inf(cv.FP_OPS, agg_r) | cv.is_inf(cv.F2_OPS, h)
+        acc_inf = cv.is_inf(cv.F2_OPS, sig_acc)
+        mask = jnp.concatenate([~g1_inf, ~acc_inf], axis=0)
 
-    ax, ay = _affine_g1(agg_r)
-    # h and sig_acc convert to affine as ONE stacked instance: the lane
-    # concat the multi-pairing needs anyway happens BEFORE to_affine, so
-    # the f2 inversion graph is instantiated once, not twice
-    g2cat = jax.tree_util.tree_map(
-        lambda a, b: jnp.concatenate([a, b], axis=-1), h, sig_acc
-    )
-    qx, qy = _affine_g2(g2cat)
-    gx, gy = _neg_g1_gen((1,))
+        ax, ay = _affine_g1(agg_r)
+        # h and sig_acc convert to affine as ONE stacked instance: the
+        # lane concat the multi-pairing needs anyway happens BEFORE
+        # to_affine, so the f2 inversion graph is instantiated once
+        g2cat = jax.tree_util.tree_map(
+            lambda a, b: jnp.concatenate([a, b], axis=-1), h, sig_acc
+        )
+        qx, qy = _affine_g2(g2cat)
+        gx, gy = _neg_g1_gen((1,))
 
-    px = jnp.concatenate([ax, gx], axis=1)
-    py = jnp.concatenate([ay, gy], axis=1)
+        px = jnp.concatenate([ax, gx], axis=1)
+        py = jnp.concatenate([ay, gy], axis=1)
 
-    out = pr.multi_pairing((px, py), (qx, qy), mask, axis=-1)
+    # pr.multi_pairing, split at its two stages
+    with jax.named_scope("miller_loop"):
+        f = pr.f12_prod(pr.miller_loop((px, py), (qx, qy), mask), axis=-1)
+    with jax.named_scope("final_exponentiation"):
+        out = pr.final_exponentiation(f)
     return tw.f12_is_one(out) & sub_ok
 
 
@@ -324,30 +338,39 @@ def per_set_verify_kernel(pk, sig, u0, u1, real):
     that).
 
     Returns (all_ok: scalar bool over real lanes, per_set: (n,) bool).
+    Stages are named as in the batched kernel (no blinding ladders or
+    signature tree-sum here).
     """
-    agg = cv.point_tree_sum(cv.FP_OPS, pk, axis=-1)
-    sub_ok = cv.g2_in_subgroup(sig)
-    h = h2c.hash_to_g2_device(u0, u1)
+    with jax.named_scope("pubkey_tree_sum"):
+        agg = cv.point_tree_sum(cv.FP_OPS, pk, axis=-1)
+    with jax.named_scope("g2_subgroup_check"):
+        sub_ok = cv.g2_in_subgroup(sig)
+    with jax.named_scope("hash_to_g2"):
+        h = h2c.hash_to_g2_device(u0, u1)
 
-    agg_inf = cv.is_inf(cv.FP_OPS, agg)
-    sig_inf = cv.is_inf(cv.F2_OPS, sig)
+    with jax.named_scope("to_affine"):
+        agg_inf = cv.is_inf(cv.FP_OPS, agg)
+        sig_inf = cv.is_inf(cv.F2_OPS, sig)
 
-    ax, ay = _affine_g1(agg)
-    # one stacked affine instance for h ‖ sig (see batched kernel)
-    g2cat = jax.tree_util.tree_map(
-        lambda a, b: jnp.concatenate([a, b], axis=-1), h, sig
-    )
-    qx, qy = _affine_g2(g2cat)
-    n = ax.shape[1]
-    gx, gy = _neg_g1_gen((n,))
+        ax, ay = _affine_g1(agg)
+        # one stacked affine instance for h ‖ sig (see batched kernel)
+        g2cat = jax.tree_util.tree_map(
+            lambda a, b: jnp.concatenate([a, b], axis=-1), h, sig
+        )
+        qx, qy = _affine_g2(g2cat)
+        n = ax.shape[1]
+        gx, gy = _neg_g1_gen((n,))
 
-    px = jnp.concatenate([ax, gx], axis=1)
-    py = jnp.concatenate([ay, gy], axis=1)
-    mask = jnp.concatenate([~agg_inf, ~sig_inf], axis=0)
-    f = pr.miller_loop((px, py), (qx, qy), mask)
-    f1 = jax.tree_util.tree_map(lambda x: x[..., :n], f)
-    f2 = jax.tree_util.tree_map(lambda x: x[..., n:], f)
-    out = pr.final_exponentiation(tw.f12_mul(f1, f2))
+        px = jnp.concatenate([ax, gx], axis=1)
+        py = jnp.concatenate([ay, gy], axis=1)
+        mask = jnp.concatenate([~agg_inf, ~sig_inf], axis=0)
+    with jax.named_scope("miller_loop"):
+        f = pr.miller_loop((px, py), (qx, qy), mask)
+        f1 = jax.tree_util.tree_map(lambda x: x[..., :n], f)
+        f2 = jax.tree_util.tree_map(lambda x: x[..., n:], f)
+        f = tw.f12_mul(f1, f2)
+    with jax.named_scope("final_exponentiation"):
+        out = pr.final_exponentiation(f)
     per_set = tw.f12_is_one(out) & sub_ok & ~sig_inf & ~agg_inf
     all_ok = jnp.all(per_set | ~real)
     return all_ok, per_set
@@ -428,18 +451,13 @@ def _trace_chunk(tr, host_prep_ms, t_dev0, n_sets, n_pad, per_set=False,
     of where device time goes that histograms can't give.
     `overlap_ratio`: fraction of this chunk's host prep that ran while
     the device executed the previous chunk (0 on the serial path).
-    `shards`: devices this launch was split across (1 = single device);
-    `shard_lanes`/`shard_occupancy` give the per-device view of the
-    same padding economics."""
-    shards = max(int(shards), 1)
+    `shards`: devices this launch was split across (1 = single device;
+    each holds lanes / shards of them)."""
     tr.add_span(
         "device_chunk", t_dev0, _time.monotonic(),
         sets=n_sets, lanes=n_pad,
-        pad_ratio=round(n_pad / max(n_sets, 1), 3),
         occupancy=round(n_sets / max(n_pad, 1), 3),
-        shards=shards,
-        shard_lanes=n_pad // shards,
-        shard_occupancy=round(n_sets / max(n_pad, 1), 3),
+        shards=max(int(shards), 1),
         host_prep_ms=round(host_prep_ms, 3),
         overlap_ratio=round(overlap_ratio, 3),
         per_set=per_set,
@@ -527,8 +545,20 @@ def execute_chunk(prepared, overlap_ratio=None):
     return out
 
 
-def _verify_chunk(sets, dst, rng, min_sets=1, min_pks=1):
-    return execute_chunk(prepare_chunk(sets, dst, rng, min_sets, min_pks))
+@_contextmanager
+def _inline_prep(chunk, n_sets):
+    """The serial path's host stage, run on the calling thread: the
+    chunk's `prep`, inside the `prep_wait` its caller spends on it (the
+    pipelined dispatcher's wait for the prep thread)."""
+    with tracing.span("prep_wait", chunk=chunk, drain=False), \
+            tracing.span("prep", chunk=chunk, sets=n_sets):
+        yield
+
+
+def _verify_chunk(sets, dst, rng, min_sets=1, min_pks=1, chunk=0):
+    with _inline_prep(chunk, len(sets)):
+        prepared = prepare_chunk(sets, dst, rng, min_sets, min_pks)
+    return execute_chunk(prepared)
 
 
 def _batch_m_pad(sets):
@@ -584,19 +614,22 @@ def verify_signature_sets(sets, dst=DST_POP, rng=None):
     m_pad = _batch_m_pad(sets)
     for i in range(0, len(sets), B):
         if not _verify_chunk(sets[i:i + B], dst, rng,
-                             min_sets=B, min_pks=m_pad):
+                             min_sets=B, min_pks=m_pad, chunk=i // B):
             return False
     return True
 
 
-def _per_set_chunk(sets, dst, min_sets=1, min_pks=1):
+def _per_set_chunk(sets, dst, min_sets=1, min_pks=1, chunk=0):
+    sets = list(sets)
     tr = tracing.current_trace()
     t0 = _time.monotonic()
-    prep = _prepare(sets, dst, min_sets, min_pks)
+    with _inline_prep(chunk, len(sets)):
+        prep = _prepare(sets, dst, min_sets, min_pks)
+        if prep is not None:
+            sets, n_pad, pk, sig, u0, u1 = prep
+            real = jnp.asarray(np.arange(n_pad) < len(sets))
     if prep is None:
-        return [False] * len(list(sets))
-    sets, n_pad, pk, sig, u0, u1 = prep
-    real = jnp.asarray(np.arange(n_pad) < len(sets))
+        return [False] * len(sets)
     t1 = _time.monotonic()
     plan = _shard.get_mesh_plan()
     args, shards = plan.place_verify_args((pk, sig, u0, u1, real))
@@ -679,5 +712,5 @@ def verify_signature_sets_per_set(sets, dst=DST_POP):
     out = []
     for i in range(0, len(sets), B):
         out.extend(_per_set_chunk(sets[i:i + B], dst,
-                                  min_sets=B, min_pks=m_pad))
+                                  min_sets=B, min_pks=m_pad, chunk=i // B))
     return out

@@ -44,6 +44,7 @@ import time
 import jax
 
 from ...utils import metrics as _metrics
+from ...utils import tracing
 from ...utils.logging import get_logger
 
 log = get_logger("crypto")
@@ -744,20 +745,27 @@ class CachedKernel:
             return self._timed(exe, args, "aot")
 
     def _timed(self, runner, args, source):
-        """Execute and feed the profile registry: wall time around the
-        call INCLUDING block_until_ready, so the registry records device
-        wall rather than async-dispatch wall.  Profiling failures never
-        fail a launch — the result is already in hand."""
-        t0 = time.monotonic()
-        out = jax.block_until_ready(runner(*args))
-        wall = time.monotonic() - t0
+        """Execute under the `launch` span and feed the profile registry:
+        wall time around the call INCLUDING block_until_ready, so both
+        record device wall rather than async-dispatch wall.  One pair of
+        clock reads serves both sinks; the ring span needs a current
+        trace, the profiler annotation does not.  Profiling failures
+        never fail a launch — the result is already in hand."""
+        tr = tracing.current_trace()
+        with tracing.region("launch", tr) as parent:
+            t0 = time.monotonic()
+            out = jax.block_until_ready(runner(*args))
+            t1 = time.monotonic()
         try:
             from . import profile
 
             sig, _ = _shape_sig(args)
+            label = CompileCache._label_from_sig(sig)
+            if tr is not None:
+                tr.add_span("launch", t0, t1, parent=parent,
+                            kernel=self.name, shape=label, source=source)
             profile.get_registry().record_launch(
-                self.name, CompileCache._label_from_sig(sig), wall,
-                source=source,
+                self.name, label, t1 - t0, source=source,
             )
         except Exception as e:
             log.debug("kernel profile record failed for %s: %s",

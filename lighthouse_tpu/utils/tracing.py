@@ -17,10 +17,28 @@ Usage contract:
     thread appends the stage spans before resolving the future
   * `finish()` publishes the trace into the ring buffer (idempotent)
 
+The verify path's spans, by thread (`verify_batch` traces):
+
+  dispatcher   queue_wait, batch, kernel, attribution, prep_wait, launch,
+               device_chunk, and prep on the serial (one-chunk) path
+  prep thread  prep, one per chunk staged by a pipelined batch, drained
+               chunks included
+
 Span timestamps are time.monotonic() seconds; each trace additionally
 records one wall-clock timestamp at creation for display.  Spans may
 start before the trace was created (a queued request's submit time) —
 their relative start_ms is simply negative.
+
+Live spans: `Trace.span(...)` and the module-level `span(...)` time the
+block they wrap.  While the block runs, the span is the innermost
+*region* of its thread.  A span records the enclosing region's name in
+a `parent` attribute (None at the top of a thread), so its self time is
+its duration less its children's.  Every region also opens a
+`jax.profiler.TraceAnnotation` of the same name, carrying the trace id:
+while a profiler session runs, the spans lie on its host plane, on the
+device trace's own clock.  Outside a session the annotation costs a few
+hundred nanoseconds.  `region(...)` alone marks a block whose ring span
+its caller records itself (the dispatcher's `kernel`).
 
 Trace ids are NODE-UNIQUE strings ``<node>-<seq>``: the counter alone
 is process-local and collides the moment two nodes' traces meet (the
@@ -99,11 +117,13 @@ class Trace:
 
     @contextmanager
     def span(self, name, **attrs):
-        t0 = time.monotonic()
-        try:
-            yield self
-        finally:
-            self.add_span(name, t0, time.monotonic(), **attrs)
+        with region(name, self) as parent:
+            t0 = time.monotonic()
+            try:
+                yield self
+            finally:
+                self.add_span(name, t0, time.monotonic(), parent=parent,
+                              **attrs)
 
     def finish(self, **attrs):
         with self._lock:
@@ -135,6 +155,7 @@ class Trace:
             "trace_id": self.trace_id,
             "kind": self.kind,
             "wall_start": round(self.wall_start, 6),
+            "mono_start": round(self.t_start, 6),
             "duration_ms": round((t_end - self.t_start) * 1e3, 3),
             "attrs": attrs,
             "spans": [
@@ -173,6 +194,52 @@ def use(trace):
         yield trace
     finally:
         stack.pop()
+
+
+_ANNOTATION = None      # jax.profiler.TraceAnnotation, imported on first use
+
+
+def _annotation(name, trace):
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    if trace is None:
+        return _ANNOTATION(name)
+    return _ANNOTATION(name, trace_id=trace.trace_id)
+
+
+@contextmanager
+def region(name, trace=None):
+    """Make `name` the calling thread's innermost live region for the
+    block, under a profiler annotation of the same name that carries
+    `trace`'s id.  Yields the enclosing region's name (None at the top
+    of the thread).  Records no span: `span` does, or the caller."""
+    live = getattr(_TLS, "live", None)
+    if live is None:
+        live = _TLS.live = []
+    parent = live[-1] if live else None
+    live.append(name)
+    try:
+        with _annotation(name, trace):
+            yield parent
+    finally:
+        live.pop()
+
+
+@contextmanager
+def span(name, trace=None, **attrs):
+    """Live span `name` on `trace` (default: the thread's current
+    trace).  Without a trace the block still opens its region and
+    annotation, and nothing is recorded."""
+    trace = current_trace() if trace is None else trace
+    if trace is None:
+        with region(name):
+            yield None
+        return
+    with trace.span(name, **attrs):
+        yield trace
 
 
 def depth():

@@ -97,6 +97,19 @@ def verify_with_verdicts(verifier, sets, priority="attestation"):
     )
 
 
+def _next_staged(out_q, producer):
+    """The next (t0, t1, item) the prep thread staged; None once it has
+    exited without staging one.  Empty alone does NOT mean the producer
+    died — a slow prep can exceed any fixed timeout — so only a dead
+    thread ends the wait."""
+    while True:
+        try:
+            return out_q.get(timeout=0.25)
+        except Empty:
+            if not producer.is_alive():
+                return None
+
+
 class QueueFullError(RuntimeError):
     """Admission control: the request's class queue is at capacity."""
 
@@ -860,22 +873,33 @@ class VerificationService:
         to coordinate with service shutdown — stop() during a pipelined
         dispatch lets this method finish normally (draining every staged
         chunk in the finally) and the running batch's futures resolve;
-        only still-queued requests fail with ServiceStopped."""
+        only still-queued requests fail with ServiceStopped.
+
+        Spans on the batch trace: `prep` per chunk staged (prep thread,
+        drained chunks included) and `prep_wait` around each wait for a
+        staged chunk (dispatcher; `drain` when that chunk will not be
+        launched)."""
         out_q = Queue(maxsize=1)
+        # thread-local current traces do not cross into the prep thread:
+        # it is handed the batch trace here
+        trace = tracing.current_trace()
 
         def produce():
-            for chunk in chunks:
-                t0 = time.monotonic()
-                try:
-                    # chaos seam: an injected prep fault aborts the
-                    # pipeline; _verify_batch falls back to the plain
-                    # path, so the batch still verifies correctly
-                    failpoints.hit("verify.prep")
-                    item = prepare(chunk)
-                except BaseException as e:   # delivered, not raised: the
-                    out_q.put((t0, time.monotonic(), e))
-                    return                   # dispatcher owns error handling
-                out_q.put((t0, time.monotonic(), item))
+            with tracing.use(trace):
+                for i, chunk in enumerate(chunks):
+                    t0 = time.monotonic()
+                    try:
+                        with tracing.span("prep", chunk=i, sets=len(chunk)):
+                            # chaos seam: an injected prep fault aborts
+                            # the pipeline; _verify_batch falls back to
+                            # the plain path, so the batch still
+                            # verifies correctly
+                            failpoints.hit("verify.prep")
+                            item = prepare(chunk)
+                    except BaseException as e:   # delivered, not raised:
+                        out_q.put((t0, time.monotonic(), e))
+                        return       # the dispatcher owns error handling
+                    out_q.put((t0, time.monotonic(), item))
 
         t = threading.Thread(
             target=produce, name="verify_service_prep", daemon=True
@@ -887,7 +911,9 @@ class VerificationService:
         prev_exec = None
         try:
             for _ in range(len(chunks)):
-                p0, p1, prepared = out_q.get()
+                with tracing.span("prep_wait", trace, chunk=consumed,
+                                  drain=not ok):
+                    p0, p1, prepared = out_q.get()
                 consumed += 1
                 if isinstance(prepared, BaseException):
                     raise prepared
@@ -909,18 +935,15 @@ class VerificationService:
         finally:
             # if execute raised, the producer may be blocked on the full
             # handoff queue: drain until it has delivered every chunk (or
-            # exited).  Empty alone does NOT mean the producer died — a
-            # slow prep can exceed any fixed timeout — so only a dead
-            # thread ends the drain early.
+            # exited early on its own error)
             while consumed < len(chunks):
-                try:
-                    _, _, item = out_q.get(timeout=0.25)
-                except Empty:
-                    if not t.is_alive():
-                        break   # exited early on its own error
-                    continue    # still prepping — keep draining
+                with tracing.span("prep_wait", trace, chunk=consumed,
+                                  drain=True):
+                    staged = _next_staged(out_q, t)
+                if staged is None:
+                    break
                 consumed += 1
-                if isinstance(item, BaseException):
+                if isinstance(staged[2], BaseException):
                     break       # producer stopped after delivering this
         if overlaps:
             mean = sum(overlaps) / len(overlaps)
@@ -1127,7 +1150,9 @@ class VerificationService:
         t_k0 = time.monotonic()
         bt.add_span("batch", now, t_k0, **batch_attrs)
         try:
-            with tracing.use(bt):
+            # `kernel` is recorded below from t_k0/t_k1; the region makes
+            # it the parent of the spans the backend opens meanwhile
+            with tracing.use(bt), tracing.region("kernel", bt):
                 if probe_cap is not None and len(all_sets) > probe_cap:
                     ok = self._verify_probe_split(all_sets, probe_cap)
                 else:
