@@ -3,9 +3,9 @@
 Representation (round-3 "lazy reduction" redesign): an Fp element is an
 ``int32`` array of shape ``(49, *batch)`` — 49 little-endian 8-bit SIGNED
 limbs, value kept in **Montgomery form** (x·R mod p, R = 2^392) but only
-LAZILY reduced: |value| stays within a few multiples of p and limb
-magnitudes stay small enough that every product is exact in f32, yet no
-carry propagation happens outside `mont_mul`.
+LAZILY reduced: limb magnitudes stay small enough that every product is
+exact in f32, yet no full carry propagation happens outside the zero
+tests (`is_zero`, `canonical`).
 
 Why this shape:
   * 8-bit limbs make the schoolbook product a set of f32-exact diagonal
@@ -13,27 +13,32 @@ Why this shape:
     < 2^24 are exactly representable in f32 — the MXU/VPU-friendly core.
   * SIGNED limbs make subtraction a single elementwise op (a - b), with
     no borrow chain and no additive-constant tricks.
-  * The 49th limb (R = 2^392 instead of 2^384) buys 2^10.35 of headroom
-    over p ~ 2^381.65, which is what lets values wander in (-Bp, +Bp)
-    between reductions: the Montgomery step maps inputs of magnitude
-    B·p to outputs of magnitude ~(B^2·2^-10.35 + 1.008)·p, a contraction
-    with fixed point B ~ 2.02 — chains of ~30 lazy additions between
-    multiplications stay far inside the representable range.
+  * The 49th limb (R = 2^392 instead of 2^384) is headroom above p <
+    2^381.  `mont_mul` first compresses each operand (value kept mod p,
+    spills wrapped through 2^392 mod p and 2^400 mod p) to limbs in
+    [-254, 510] with a top limb in [-1, 256], whatever its lazy history;
+    so products stay f32-exact and every output is an exact N-limb
+    integer with |limbs| <= 260 (`mont_mul`).  The lazy domain is closed
+    by limb bounds: chains of ~30 lazy additions between multiplications
+    stay far inside |limbs| < 2^22.
   * `add`/`sub`/`neg` are ONE elementwise HLO op each (round-2 cost:
     a 48-step `lax.scan` carry/borrow chain per call).  `mont_mul` costs
-    three shift-formulation column products, two fold passes and ONE
-    carry scan.  XLA compile time for the pairing graph is linear in
-    per-field-op HLO cost (measured in round 3), so this representation is
-    the second half of the compile-cliff fix — and removes ~10^2 sequential
-    48-step loops per curve op at RUNTIME, which is what the TPU VPU
-    actually cares about.
+    three shift-formulation column products and a handful of fold
+    passes, with NO sequential loop: in the exact division by R
+    (`_exact_div_R`) the folded low half's limbs below its top are worth
+    0 or 2^384, and one compare on limb 47 says which.  XLA compile time
+    for the pairing graph is linear in per-field-op HLO cost (measured in
+    round 3), so this representation is the second half of the
+    compile-cliff fix; on TPU every scan step is one iteration of a
+    device while loop, which is why the multiply has none.
 
-Zero tests and equality are the only places full reduction happens:
-`is_zero` compresses through one Montgomery step (zero is preserved),
-adds 4p, carry-propagates once, and compares against the five canonical
-multiples of p its range admits.  `canonical` (for sgn0 / compressed-
-point sign rules) additionally subtracts the right multiple of p picked
-by a scan-free lexicographic compare.
+Zero tests and equality are the only places full reduction happens, and
+the only carry scans (`_carry_scan`): `is_zero` compresses through one
+Montgomery step (zero is preserved), adds 4p, carry-propagates once, and
+compares against the five canonical multiples of p its range admits.
+`canonical` (for sgn0 / compressed-point sign rules) additionally
+subtracts the right multiple of p picked by a scan-free lexicographic
+compare.
 
 This mirrors what blst does in spirit — redundant representations,
 reduction only where semantics demand it (/root/reference/crypto/bls/
@@ -154,8 +159,8 @@ def _carry_scan(cols, n_out):
     """Propagate carries over signed `cols` (M, *batch), |cols| < 2^30.
 
     Returns (n_out normalized limbs in [0, 255], final signed carry).
-    One sequential `lax.scan`: this is the ONLY scan in the field layer,
-    paid once per `mont_mul`/`is_zero`, never per add/sub.
+    One sequential `lax.scan`, paid by `is_zero` and `canonical` only:
+    never per add/sub/mont_mul.
     """
     init = jnp.zeros(cols.shape[1:], I32)
 
@@ -236,9 +241,11 @@ def _fold3_w(cols):
 
 def _compress_limbs(a):
     """Value-preserving compression of NLIMB signed limbs: |limbs| < 2^22
-    in, |limbs| <= ~260 out, value congruent mod p (spills wrapped).
-    Three passes bound the wrap feedback: the wrap constants' top limbs
-    are tiny, so each pass shrinks the spill by ~2^8."""
+    in; out, limbs in [-254, 510] and the top limb in [-1, 256] (interval
+    bounds over the three passes), so the value lies in (-2·2^384,
+    258·2^384), congruent mod p (spills wrapped).  Three passes bound
+    the wrap feedback: the wrap constants' top limbs are tiny, so each
+    pass shrinks the spill by ~2^8."""
     assert a.shape[0] == NLIMB, a.shape
     return _fold_w(_fold_w(_fold3_w(a)))
 
@@ -247,6 +254,59 @@ def _compress_mod_R(a, n_out=NLIMB):
     """Truncating compression — ONLY for quantities defined mod R
     (the Montgomery quotient m)."""
     return _fold(_fold3(a, n_out), n_out)
+
+
+def _fold_keep(cols):
+    """Exact fold that keeps the top limb whole: every limb below the top
+    splits into its low byte and a carry one limb up; the top limb takes
+    its neighbour's carry and keeps its own high part (no wrap, no
+    truncation: the value is unchanged)."""
+    hi = cols >> LB
+    lo = jnp.concatenate([cols[:-1] & MASK, cols[-1:]], axis=0)
+    return lo + jnp.concatenate([jnp.zeros_like(cols[:1]), hi[:-1]], axis=0)
+
+
+def _fold3_keep(cols):
+    """Three-byte `_fold_keep`: limb k < N-2 spreads its bytes over limbs
+    k..k+2; limb N-2 keeps its low byte and hands the rest, one limb up,
+    to the top limb, which stays whole."""
+    b1 = (cols >> LB) & MASK
+    b2 = cols >> (2 * LB)
+    z = jnp.zeros_like(cols[:2])
+    out = jnp.concatenate([cols[:-1] & MASK, cols[-1:]], axis=0)
+    s1 = jnp.concatenate([z[:1], b1[:-2], cols[-2:-1] >> LB], axis=0)
+    s2 = jnp.concatenate([z, b2[:-2]], axis=0)
+    return out + s1 + s2
+
+
+def _compress_keep(cols):
+    """Exact compression of |cols| < 2^24: every limb below the top lands
+    in [-1, 257], the top limb holds the rest of the value.  The 3-byte
+    fold leaves those limbs in [-256, 765] (b2 in [-256, 255]); the
+    1-byte fold adds carries in [-1, 2] to low bytes in [0, 255]."""
+    return _fold_keep(_fold3_keep(cols))
+
+
+def _exact_div_R(u):
+    """u / R, exactly, for columns u (2N, *batch), |u| < 2^24 - 2^16,
+    whose value is a multiple of R: N limbs, the N-1 below the top in
+    [-1, 257].
+
+    No carry chain.  The low half compresses to a value c·R whose limbs
+    below the top are in [-1, 257], so their value S lies in
+    (-2^384/255, 1.008·2^384); S ≡ 0 mod 2^384 (the top limb's weight),
+    so S is 0 or 2^384.  Limb N-2 tells which: S = 2^384 forces it to
+    >= 255, S = 0 to <= 0, so it is > 128 iff S = 2^384.  Then
+    c = (top + [S = 2^384]) / 256 exactly, and c joins the high half's
+    limb 0 (|c| < 2^16, so |u| + |c| stays < 2^24)."""
+    low = _compress_keep(u[:NLIMB])
+    # (1, *batch) row slices, not int indices: the Pallas kernel
+    # (pallas_fp) runs this function too, and Mosaic lowers static
+    # slices only
+    spill = (low[-2:-1] > (1 << (LB - 1))).astype(I32)
+    carry = (low[-1:] + spill) >> LB
+    high = jnp.concatenate([u[NLIMB:NLIMB + 1] + carry, u[NLIMB + 1:]], axis=0)
+    return _compress_keep(high)
 
 
 # public alias: ops whose outputs feed a mul-free linear recurrence (the
@@ -371,35 +431,31 @@ def neg(a):
 def mont_mul(a, b):
     """Montgomery product a·b·R^-1 mod p (SOS method, lazy domain).
 
-    Accepts lazily-reduced inputs (|limbs| < 2^22, |value| < ~1000p);
-    returns |value| < ~2.3p with limbs in [0,255] plus a {-1,0} top limb.
-    Cost: 2 compressions + 3 column products + ONE carry scan.
+    Accepts lazily-reduced inputs (|limbs| < 2^22, any value); returns
+    the integer u/R exactly: limbs below the top in [-1, 257], the top
+    limb in [-3, 260].  Cost: 2 compressions + 3 column products +
+    `_exact_div_R`'s four folds and one compare; no sequential loop.
 
-    Correctness: with folded limbs <= 258, every f32 product column is
-    exact (< 2^24); m = t·(-p^-1) mod R is computed mod R by truncating
-    folds at NLIMB; u = t + m·p is ≡ 0 (mod R) as a VALUE even though its
-    columns are nonzero, so after one full carry propagation the low
-    NLIMB limbs are exactly zero and the high limbs (plus the final
-    signed carry at weight 2^384... i.e. limb NLIMB-1 of the shifted
-    result) are u/R.  |u/R| <= |a||b|/R + p < (B^2·2^-10.35 + 1.008)p —
-    the contraction that makes the lazy domain closed (module docstring).
+    Correctness: the compressed operands a', b' have limbs in [-254, 510]
+    and values in (-2·2^384, 258·2^384) (`_compress_limbs`), so every
+    f32 product column is exact (49·510^2 < 2^24); m = t·(-p^-1) mod R
+    is computed mod R by truncating folds, limbs in [-1, 257], so m lies
+    in (-R/255, 1.008·R); u = t + m·p has columns in (-2^23, 2^24 - 2^16)
+    and is ≡ 0 (mod R) as a VALUE even though its columns are nonzero,
+    so `_exact_div_R(u)` is u/R.  u/R = (a'·b' + m·p)/R lies in
+    (-2.02·2^384, 260.13·2^384) (p < 0.102·2^384), and the limbs below
+    the top are worth (-2^384/255, 1.008·2^384), so the top limb, their
+    difference over 2^384, is an integer in [-3, 260].
     """
     ar = _compress_limbs(a)
     br = _compress_limbs(b)
-    cols_t = _mul_cols(ar, br)                        # (2N, *batch) |.|<2^23
+    cols_t = _mul_cols(ar, br)                        # (2N, *batch) |.|<2^24
     t_red = _compress_mod_R(cols_t[:NLIMB])           # == t mod R
     np_arr = jnp.asarray(NPRIME_LIMBS)[(...,) + (None,) * (cols_t.ndim - 1)]
     m_red = _compress_mod_R(_mul_cols(t_red, np_arr, NLIMB))
     p_arr = jnp.asarray(P_LIMBS)[(...,) + (None,) * (cols_t.ndim - 1)]
-    u = _mul_cols(m_red, p_arr) + cols_t              # ≡ 0 mod R, |.|<2^23
-    full, carry = _carry_scan(u, 2 * NLIMB)           # low NLIMB limbs = 0
-    res = full[NLIMB:]                                # (NLIMB-1...) see below
-    # full has 2N limbs; res = limbs N..2N-1 (N of them).  The scan's
-    # final carry has weight 2^(8*2N) -> /R = weight 2^(8*(2N - N)) =
-    # limb N of res — one PAST the top: fold it into the top limb with
-    # weight 256 (exact: carry ∈ {-1, 0}).
-    top = res[-1] + carry * (1 << LB)
-    return jnp.concatenate([res[:-1], top[None]], axis=0)
+    u = _mul_cols(m_red, p_arr) + cols_t              # ≡ 0 mod R
+    return _exact_div_R(u)
 
 
 def mont_sqr(a):

@@ -1,29 +1,29 @@
 """Experimental Pallas kernel: fused Montgomery multiplication.
 
 The default `fp.mont_mul` is a chain of XLA ops (input compressions, three
-`_mul_cols` contractions, redundant folds, one carry scan); XLA fuses much
-of it, but every stage still round-trips intermediates at the fusion
-boundaries.  This kernel runs the WHOLE lazy-domain SOS Montgomery
+`_mul_cols` contractions, redundant folds, the exact division by R); XLA
+fuses much of it, but every stage still round-trips intermediates at the
+fusion boundaries.  This kernel runs the WHOLE lazy-domain SOS Montgomery
 multiply — both limb-product contractions, the Montgomery-quotient
-contraction, the value-preserving input compressions, and the final carry
-propagation (statically unrolled) — as ONE `pallas_call` per batch tile:
-operands land in VMEM once, the three contractions hit the MXU
-back-to-back, and only the reduced result returns to HBM (pallas_guide.md:
-HBM->VMEM->compute).
+contraction, the value-preserving input compressions, and the exact
+division by R — as ONE `pallas_call` per batch tile: operands land in
+VMEM once, the three contractions hit the MXU back-to-back, and only the
+reduced result returns to HBM (pallas_guide.md: HBM->VMEM->compute).
 
 It is a bit-for-bit mirror of `fp.mont_mul` on the lazy representation
-(49 signed int32 limbs, R = 2^392, fp.py module docstring).  The fold
-pipeline is REIMPLEMENTED here rather than calling fp's helpers: pallas
-rejects kernel bodies that capture constants, and fp's folds close over
-the R392/R400 wrap arrays — so those constants are threaded in as refs
-instead.  Drift between the two copies is caught by the bit-equality
-asserts in tests/test_pallas_fp.py (full pipeline, multiple tile shapes
-and edge values).  Only the column contraction intentionally differs:
-f32 dots against constant gather matrices (the MXU-friendly form;
-`fp._mul_cols_shift`'s reshape trick exists to keep the *XLA graph*
-small, which is irrelevant within a single fused kernel) — exact, so
-bit-identity still holds.  The f32 exactness argument is fp.py's:
-compressed limbs <= ~260, products < 2^18, 49-term sums < 2^24.
+(49 signed int32 limbs, R = 2^392, fp.py module docstring).  The input
+fold pipeline is REIMPLEMENTED here rather than calling fp's helpers:
+pallas rejects kernel bodies that capture constants, and fp's folds close
+over the R392/R400 wrap arrays — so those constants are threaded in as
+refs instead.  The division by R is fp's own `_exact_div_R`, which
+captures no array.  Drift between the two copies is caught by the
+bit-equality asserts in tests/test_pallas_fp.py (full pipeline, multiple
+tile shapes and edge values).  Only the column contraction intentionally
+differs: f32 dots against constant gather matrices (the MXU-friendly
+form; `fp._mul_cols_shift`'s reshape trick exists to keep the *XLA
+graph* small, which is irrelevant within a single fused kernel) — exact,
+so bit-identity still holds.  The f32 exactness argument is fp.py's:
+compressed limbs <= 510, products < 2^18, 49-term sums < 2^24.
 
 Status: bit-identical to `fp.mont_mul` in interpreter mode, and lowers
 for v5e through Mosaic (tests/test_tpu_compile.py); never run on a chip.
@@ -113,18 +113,7 @@ def _mont_body(a, b, d2n, dn, npl, pconst, r392c, r400c):
     )
     p_f = jnp.broadcast_to(pconst.astype(jnp.float32)[:, None], a.shape)
     u = cols(m_red.astype(jnp.float32), p_f, d2n).astype(jnp.int32) + cols_t
-
-    # carry propagation over the 2N columns, unrolled statically: Mosaic
-    # lowers static row slices, not the dynamic_slice a lax.scan emits
-    carry = z1
-    res = []
-    for i in range(2 * NLIMB):
-        t = u[i:i + 1] + carry
-        carry = t >> LB
-        if i >= NLIMB:
-            res.append(t & MASK)                          # u / R rows
-    res[-1] = res[-1] + carry * (1 << LB)
-    return jnp.concatenate(res, axis=0)
+    return fp._exact_div_R(u)
 
 
 def _mont_mul_kernel(
@@ -133,8 +122,7 @@ def _mont_mul_kernel(
     """One tile: a, b (N, TILE) i32 lazy -> out (N, TILE) i32 lazy.
 
     Bit-for-bit mirror of fp.mont_mul: _compress_limbs on both operands,
-    cols_t, t mod R, m = t*(-p^-1) mod R, u = m*p + t, one carry scan,
-    upper half + final carry folded into the top limb.
+    cols_t, t mod R, m = t*(-p^-1) mod R, u = m*p + t, u / R.
     """
     out_ref[:] = _mont_body(
         a_ref[:], b_ref[:], d2n_ref[:], dn_ref[:], np_ref[:], p_ref[:],

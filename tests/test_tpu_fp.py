@@ -124,3 +124,186 @@ def test_multi_dim_batch():
     a = to_dev(xs).reshape(fp.NLIMB, 2, 3)
     out = fp.mont_mul(a, a).reshape(fp.NLIMB, 6)
     assert from_dev(out) == [(x * x) % P for x in xs]
+
+
+# ------------------------------------------- mont_mul on the lazy domain
+
+R_INV = pow(fp.R_INT, -1, P)
+EDGES = [0, 1, P - 1, P, -1, -P, -5, 1000 * P, -1000 * P]
+
+
+def lazy_limbs(values, bound, signed=True):
+    """(NLIMB, n) int32 limbs holding each value exactly.  signed: digits
+    in [-128, 127] made redundant by random value-preserving moves of
+    up to bound/256 between neighbours, so limbs come near `bound`;
+    unsigned: bytes in [0, 255] below a signed top limb (a negative value
+    then has top limb < 0, which `_compress_limbs` wraps)."""
+    cols = []
+    for v in values:
+        limbs = []
+        for _ in range(fp.NLIMB - 1):
+            d = (v + 128) % 256 - 128 if signed else v % 256
+            limbs.append(d)
+            v = (v - d) >> fp.LB
+        limbs.append(v)
+        r = bound // 256 - 1
+        for i in range(fp.NLIMB - 1):
+            k = rng.randint(-r, r) if r > 0 else 0
+            limbs[i] += k << fp.LB
+            limbs[i + 1] -= k
+        cols.append(limbs)
+    out = np.array(cols, dtype=np.int64).T
+    assert np.abs(out).max() < 2 ** 22
+    return out.astype(np.int32)
+
+
+def lazy_values(n):
+    return EDGES + [rng.randrange(-1000 * P, 1000 * P) for _ in range(n)]
+
+
+def assert_mont_output(out, xs, ys):
+    """`out` is mont_mul(x, y): ≡ x·y·R^-1 (mod p), limbs below the top
+    in [-1, 257], the top limb in [-3, 260] (fp.mont_mul docstring)."""
+    out = np.asarray(out)
+    got = fp.array_to_ints(out)
+    want = [RF.fp_mul(x % P, y % P) * R_INV % P for x, y in zip(xs, ys)]
+    assert [g % P for g in got] == want
+    assert out[:-1].min() >= -1 and out[:-1].max() <= 257
+    assert out[-1].min() >= -3 and out[-1].max() <= 260
+
+
+def exact_quotient(a, b):
+    """The integer mont_mul(a, b) must equal: (a'·b' + m·p) / R, with a',
+    b' the compressed operands and m the Montgomery quotient in the limb
+    form mont_mul builds, all read back as Python integers."""
+    ar, br = fp._compress_limbs(a), fp._compress_limbs(b)
+    t_red = fp._compress_mod_R(fp._mul_cols(ar, br)[:fp.NLIMB])
+    m_red = fp._compress_mod_R(fp._mul_cols(
+        t_red, jnp.asarray(fp.NPRIME_LIMBS)[:, None], fp.NLIMB))
+    ts = [x * y for x, y in zip(fp.array_to_ints(np.asarray(ar)),
+                                fp.array_to_ints(np.asarray(br)))]
+    us = [t + m * P for t, m in zip(ts, fp.array_to_ints(np.asarray(m_red)))]
+    assert all(u % fp.R_INT == 0 for u in us)
+    return [u // fp.R_INT for u in us]
+
+
+@pytest.mark.parametrize("bound_a,signed_a,bound_b,signed_b", [
+    (256, True, 256, True),                  # canonical-size limbs
+    (2 ** 21, True, 2 ** 21, True),          # limbs near 2^21
+    (256, False, 256, False),                # negative tops, wrapped ~R
+    (2 ** 21, True, 256, False),
+    (2 ** 21, False, 2 ** 21, True),
+    (2 ** 22 - 2 ** 14, True, 2 ** 22 - 2 ** 14, False),   # near 2^22
+])
+def test_mont_mul_lazy_domain(bound_a, signed_a, bound_b, signed_b):
+    """Signed redundant limbs near 2^21, values to ±1000p, and 0, 1, p-1,
+    p: the product matches the oracle, is the exact quotient u/R (the
+    same integer a carry-propagating division gives), and the limbs stay
+    in bounds."""
+    xs, ys = lazy_values(23), lazy_values(23)
+    rng.shuffle(ys)
+    a = jnp.asarray(lazy_limbs(xs, bound_a, signed_a))
+    b = jnp.asarray(lazy_limbs(ys, bound_b, signed_b))
+    assert fp.array_to_ints(a) == xs and fp.array_to_ints(b) == ys
+    out = fp.mont_mul(a, b)
+    assert_mont_output(out, xs, ys)
+    assert fp.array_to_ints(np.asarray(out)) == exact_quotient(a, b)
+
+
+def test_mont_mul_squaring_chain_stays_in_bounds():
+    """Outputs fed back as inputs (the lazy domain is closed): 20
+    squarings from the edge values keep the bounds and the value."""
+    xs = EDGES + rand_fp(7)
+    a = jnp.asarray(lazy_limbs(xs, 256, signed=False))
+    vals = list(xs)
+    for _ in range(20):
+        a = fp.mont_mul(a, a)
+        assert_mont_output(a, vals, vals)
+        vals = [v * v * R_INV % P for v in vals]
+
+
+def columns_multiple_of_R(n, low_spill):
+    """(2N, n) int32 columns in (-2^23, 2^23) whose value is a multiple
+    of R, built so `_exact_div_R`'s folded low half has its limbs below
+    the top worth 0 (low_spill False) or 2^384 (True), or random (None)."""
+    N = fp.NLIMB
+    u = np.array([[rng.randrange(-2 ** 22, 2 ** 22) for _ in range(n)]
+                  for _ in range(2 * N)], dtype=np.int64)
+    for j in range(n):
+        if low_spill is None:
+            low = sum(int(u[k, j]) << (8 * k) for k in range(N))
+            r = low % fp.R_INT                  # make the low half ≡ 0 mod R
+            for k in range(N):
+                u[k, j] -= (r >> (8 * k)) & 0xFF
+        else:
+            c = rng.randrange(-2 ** 14, 2 ** 14)
+            if low_spill:                       # 256 + 255·(2^8+…+2^376)
+                u[:N, j] = [256] + [255] * (N - 2) + [256 * c - 1]
+            else:
+                u[:N, j] = [0] * (N - 1) + [256 * c]
+    return u.astype(np.int32)
+
+
+def column_value(u):
+    return [sum(int(u[k, j]) << (8 * k) for k in range(u.shape[0]))
+            for j in range(u.shape[1])]
+
+
+@pytest.mark.parametrize("low_spill", [False, True, None])
+def test_exact_div_R_both_carry_branches(low_spill):
+    """u/R exactly, whichever value (0 or 2^384) the folded low half's
+    limbs below its top take; the branch is asserted, not assumed."""
+    u = columns_multiple_of_R(16, low_spill)
+    low = np.asarray(fp._compress_keep(jnp.asarray(u[:fp.NLIMB])))
+    spill = low[-2] > 128
+    if low_spill is None:
+        assert spill.any()
+    else:
+        assert (spill == low_spill).all()
+    out = np.asarray(fp._exact_div_R(jnp.asarray(u)))
+    vals = column_value(u)
+    assert all(v % fp.R_INT == 0 for v in vals)
+    assert fp.array_to_ints(out) == [v // fp.R_INT for v in vals]
+    assert out[:-1].min() >= -1 and out[:-1].max() <= 257
+
+
+@pytest.mark.parametrize("low_spill", [False, True])
+def test_mont_mul_both_carry_branches(monkeypatch, low_spill):
+    """Both branches of the carry bit through mont_mul itself.  Folded
+    low half worth 0 below its top: a = k·2^384 and b = j with
+    k·j ≡ 0 (mod 256) make t = a·b a multiple of R, so m = 0 and u's
+    low half is one top column (k in [0, 256) keeps a's top limb out of
+    `_compress_limbs`' wrap).  Worth 2^384: canonical operands."""
+    if low_spill:
+        a, b = to_dev(rand_fp(8)), to_dev(rand_fp(8))
+        xs = fp.array_to_ints(np.asarray(a))
+        ys = fp.array_to_ints(np.asarray(b))
+    else:
+        ks = [16, 32, 0, 64, 128, 1, 100, 8]
+        js = [16, -8, 5, 12, 2, 0, -64, 32]
+        xs, ys = [k << 384 for k in ks], js
+        a = jnp.asarray(lazy_limbs(xs, 256))
+        b = jnp.asarray(lazy_limbs(ys, 256))
+    seen = []
+    div = fp._exact_div_R
+
+    def spy(u):
+        seen.append(np.asarray(fp._compress_keep(u[:fp.NLIMB]))[-2] > 128)
+        return div(u)
+
+    monkeypatch.setattr(fp, "_exact_div_R", spy)
+    out = fp.mont_mul(a, b)
+    assert len(seen) == 1 and (seen[0] == low_spill).all()
+    assert_mont_output(out, xs, ys)
+    if not low_spill:                           # exact: t/R = k·j/256
+        assert fp.array_to_ints(np.asarray(out)) == [
+            k * j // 256 for k, j in zip(ks, js)]
+
+
+def test_mont_mul_has_no_sequential_loop():
+    """The division by R is scan-free: no scan or while in mont_mul."""
+    import jax
+
+    x = jnp.zeros((fp.NLIMB, 4), jnp.int32)
+    text = str(jax.make_jaxpr(fp.mont_mul)(x, x))
+    assert "scan" not in text and "while" not in text

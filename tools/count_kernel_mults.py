@@ -1,14 +1,26 @@
 #!/usr/bin/env python
-"""Exact per-set field-multiplication counts for the device BLS kernel.
+"""Exact per-launch counts for the device BLS kernel: field multiplications
+and sequential loop steps.
 
-Traces batched_verify_kernel on CPU with fp.mont_mul wrapped by a
-counter: every call records (instances, lane-weighted mults), giving the
-M in the roofline bound  sets/s <= T_mult(B_eff) / M_per_set
-(ROADMAP.md's reachability bound).  Pure host-side tracing — no TPU.
+Traces batched_verify_kernel on CPU at a padded (sets, pubkeys) shape,
+from `bls.example_chunk_args` (the prewarm's padding content: the traced
+program depends on shapes only).  Pure host-side tracing — no TPU.
+
+- Multiplications: fp.mont_mul wrapped by a counter; every traced call
+  records (instances, lane-weighted mults), giving the M in the roofline
+  bound  sets/s <= T_mult(B_eff) / M_per_set  (ROADMAP.md).  Static trace
+  counts: a scan body traces once.
+- Loop steps: the jaxpr walked with each scan's length multiplied through
+  the scans that enclose it, so the totals are what one launch EXECUTES.
+  On TPU every scan step is one device while-loop iteration.  Scans of
+  length NLIMB are `fp._carry_scan`'s (is_zero/canonical); length 2N was
+  mont_mul's carry scan before it was made scan-free; any other length is
+  an outer loop (Miller loop, exponentiations, ladders).
 
 Usage: python tools/count_kernel_mults.py [sets pks]...
 """
 
+import collections
 import os
 import sys
 
@@ -21,8 +33,6 @@ jax.config.update("jax_platforms", "cpu")
 
 import numpy as np  # noqa: E402
 
-from lighthouse_tpu.crypto.constants import DST_POP  # noqa: E402
-from lighthouse_tpu.crypto.ref import bls as RB  # noqa: E402
 from lighthouse_tpu.crypto.tpu import bls as tb  # noqa: E402
 from lighthouse_tpu.crypto.tpu import fp  # noqa: E402
 
@@ -47,35 +57,66 @@ class MultCounter:
         fp.mont_mul = self._orig
 
 
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for x in v if isinstance(v, (tuple, list)) else (v,):
+            if hasattr(x, "jaxpr") and hasattr(x, "consts"):   # ClosedJaxpr
+                yield x.jaxpr
+            elif hasattr(x, "eqns"):                            # Jaxpr
+                yield x
+
+
+def loop_steps(jaxpr):
+    """Executed loop work of one launch: ({scan length: [scans run, steps]},
+    equations executed, while loops met).  A while loop's trip count is
+    unknown at trace time: its body counts once and it is reported."""
+    by_len = collections.defaultdict(lambda: [0, 0])
+    totals = {"eqns": 0, "whiles": 0}
+
+    def walk(j, mult):
+        for eqn in j.eqns:
+            totals["eqns"] += mult
+            inner = mult
+            if eqn.primitive.name == "scan":
+                n = eqn.params["length"]
+                by_len[n][0] += mult
+                by_len[n][1] += mult * n
+                inner = mult * n
+            elif eqn.primitive.name == "while":
+                totals["whiles"] += mult
+            for sub in _sub_jaxprs(eqn):
+                walk(sub, inner)
+
+    walk(jaxpr, 1)
+    return dict(by_len), totals["eqns"], totals["whiles"]
+
+
 def count(n_sets, pks):
-    import random
-    rng = random.Random(7)
-    sks = [rng.randrange(1, 2**250) for _ in range(pks)]
-    pk = [RB.sk_to_pk(sk) for sk in sks]
-    sets = []
-    for i in range(n_sets):
-        msg = i.to_bytes(32, "big")
-        sig = RB.aggregate([RB.sign(sk, msg) for sk in sks])
-        sets.append(RB.SignatureSet(sig, pk, msg))
-    prep = tb._prepare(sets, DST_POP)
-    _, n_pad, pkd, sig, u0, u1 = prep
-    rands = tb._rand_scalars(n_pad)
+    args, _ = tb.example_chunk_args(n_sets, pks)
     with MultCounter() as mc:
-        jax.make_jaxpr(tb.batched_verify_kernel)(pkd, sig, u0, u1, rands)
-    # NOTE: scan bodies trace ONCE; multiply loop bodies by trip counts
-    # is NOT needed for lane-weighted *static* counts, but RUNTIME mults
-    # = static body mults x trip count for scanned segments.  The kernel
-    # wraps the miller loop + exponentiations in lax.scan, so we report
-    # both the static trace count and the runtime estimate below.
-    return mc, n_pad
+        closed = jax.make_jaxpr(tb.batched_verify_kernel)(*args)
+    return mc, closed.jaxpr
+
+
+def report(n, m):
+    mc, jaxpr = count(n, m)
+    print(f"sets={n} pks={m}: traced mont_mul instances={mc.instances} "
+          f"lane-weighted mults={mc.mults} per-set={mc.mults / n:.0f}")
+    by_len, eqns, whiles = loop_steps(jaxpr)
+    kinds = {fp.NLIMB: "carry (is_zero/canonical)",
+             2 * fp.NLIMB: "carry (mont_mul)"}
+    total = sum(s for _, s in by_len.values())
+    print(f"  executed loop steps={total} equations={eqns} "
+          f"while loops={whiles}")
+    for length in sorted(by_len):
+        runs, steps = by_len[length]
+        print(f"  scan length {length:>4}: {runs:>8} runs {steps:>9} steps"
+              f"  {kinds.get(length, 'outer')}")
 
 
 if __name__ == "__main__":
-    shapes = [(2, 1), (32, 1), (32, 64)]
+    shapes = [(32, 1), (32, 512)]
     if len(sys.argv) > 2:
         shapes = [(int(sys.argv[1]), int(sys.argv[2]))]
     for n, m in shapes:
-        mc, n_pad = count(n, m)
-        print(f"sets={n_pad} pks={m}: traced mont_mul instances="
-              f"{mc.instances} lane-weighted mults={mc.mults} "
-              f"per-set={mc.mults / n_pad:.0f}")
+        report(n, m)
