@@ -468,18 +468,22 @@ class PreparedChunk:
     """Host-stage output for one compile-bucket chunk: staged device
     arrays plus prep timing, ready for a kernel launch."""
 
-    __slots__ = ("n_sets", "n_pad", "args", "invalid", "t_prep0", "t_prep1")
+    __slots__ = ("chunk", "n_sets", "n_pad", "args", "invalid", "t_prep0",
+                 "t_prep1")
 
 
-def prepare_chunk(sets, dst=DST_POP, rng=None, min_sets=1, min_pks=1):
+def prepare_chunk(sets, dst=DST_POP, rng=None, min_sets=1, min_pks=1,
+                  chunk=0):
     """HOST stage of the two-stage verify pipeline: structural checks,
     pubkey-limb gather, padding, message hashing, blinding-scalar draw —
     everything up to (but not including) the kernel launch.  Pure host
     work, so the dispatcher's prep thread can run it for chunk N+1 while
-    the device executes chunk N."""
+    the device executes chunk N.  `chunk` is the chunk's index in its
+    batch, carried to the device stage's spans."""
     t0 = _time.monotonic()
     sets = list(sets)
     c = PreparedChunk()
+    c.chunk = chunk
     c.n_sets = len(sets)
     c.t_prep0 = t0
     prep = _prepare(sets, dst, min_sets, min_pks)
@@ -514,6 +518,29 @@ def _note_pad(kernel, args, n_sets, n_pad):
         pass
 
 
+def _place(plan, args, chunk, per_set):
+    """Mesh placement of a prepared chunk under the live span `place`:
+    a >1-device plan drops the padded pytree onto the dp/mp
+    NamedSharding layout and waits for the transfer (the launch would
+    wait for it anyway), so the span holds the transfer and not its
+    enqueue; a 1-device plan returns the pytree untouched.  Attrs:
+    `chunk`, `shards` (devices the launch is split across), `bytes`
+    (of the leaves placed), `per_set`.  Returns (args, shards)."""
+    tr = tracing.current_trace()
+    with tracing.region("place", tr) as parent:
+        t0 = _time.monotonic()
+        placed, shards = plan.place_verify_args(args)
+        if shards > 1:
+            jax.block_until_ready(placed)
+        t1 = _time.monotonic()
+    if tr is not None:
+        tr.add_span("place", t0, t1, parent=parent, chunk=chunk,
+                    shards=shards, per_set=per_set,
+                    bytes=sum(int(a.nbytes)
+                              for a in jax.tree_util.tree_leaves(args)))
+    return placed, shards
+
+
 def execute_chunk(prepared, overlap_ratio=None):
     """DEVICE stage: launch the batched kernel on a prepared chunk and
     block for the verdict.  A structurally invalid chunk is False without
@@ -529,10 +556,9 @@ def execute_chunk(prepared, overlap_ratio=None):
     tr = tracing.current_trace()
     t_dev0 = _time.monotonic()
     # mesh placement belongs to the DEVICE stage (it is the host->mesh
-    # transfer): a >1-device plan drops the padded pytree onto the
-    # dp/mp NamedSharding layout, a 1-device plan returns it untouched
+    # transfer)
     plan = _shard.get_mesh_plan()
-    args, shards = plan.place_verify_args(prepared.args)
+    args, shards = _place(plan, prepared.args, prepared.chunk, False)
     out = bool(_jit_batched(*args))
     plan.note_occupancy(prepared.n_sets, prepared.n_pad, shards)
     _note_pad("bls_batched_verify", args, prepared.n_sets, prepared.n_pad)
@@ -557,7 +583,7 @@ def _inline_prep(chunk, n_sets):
 
 def _verify_chunk(sets, dst, rng, min_sets=1, min_pks=1, chunk=0):
     with _inline_prep(chunk, len(sets)):
-        prepared = prepare_chunk(sets, dst, rng, min_sets, min_pks)
+        prepared = prepare_chunk(sets, dst, rng, min_sets, min_pks, chunk)
     return execute_chunk(prepared)
 
 
@@ -589,9 +615,11 @@ def plan_pipeline(sets, dst=DST_POP, rng=None):
         return None                      # plain path rejects structurally
     m_pad = _batch_m_pad(sets)
     chunks = [sets[i:i + B] for i in range(0, len(sets), B)]
+    index = {id(c): i for i, c in enumerate(chunks)}
 
     def prepare(chunk):
-        return prepare_chunk(chunk, dst, rng, min_sets=B, min_pks=m_pad)
+        return prepare_chunk(chunk, dst, rng, min_sets=B, min_pks=m_pad,
+                             chunk=index[id(chunk)])
 
     return chunks, prepare, execute_chunk
 
@@ -632,7 +660,7 @@ def _per_set_chunk(sets, dst, min_sets=1, min_pks=1, chunk=0):
         return [False] * len(sets)
     t1 = _time.monotonic()
     plan = _shard.get_mesh_plan()
-    args, shards = plan.place_verify_args((pk, sig, u0, u1, real))
+    args, shards = _place(plan, (pk, sig, u0, u1, real), chunk, True)
     _, out = _jit_per_set(*args)
     verdicts = [bool(v) for v in np.asarray(out)[: len(sets)]]
     plan.note_occupancy(len(sets), n_pad, shards)

@@ -123,17 +123,18 @@ def _pipeline_verdicts(sets, seed):
     return [bool(execute(prepare(c))) for c in chunks]
 
 
-def test_production_pipeline_sharded_verdicts_match(monkeypatch):
-    """ISSUE 10 acceptance: the FULL production path (plan_pipeline ->
-    prepare_chunk -> execute_chunk, mesh placement inside the device
-    stage) yields identical chunk verdicts with and without sharding for
-    the same seeded batch."""
+@pytest.mark.parametrize("mesh", ["dp=4", "dp=8"])
+def test_production_pipeline_sharded_verdicts_match(monkeypatch, mesh):
+    """The FULL production path (plan_pipeline -> prepare_chunk ->
+    execute_chunk, mesh placement inside the device stage) yields
+    identical chunk verdicts with and without sharding for the same
+    seeded batch, at the four-chip host's dp=4 and at dp=8."""
     monkeypatch.setenv("LTPU_MAX_SETS_BUCKET", "8")
     monkeypatch.delenv("LTPU_MESH", raising=False)
     sets = _oracle_sets(16)
     base = _pipeline_verdicts(sets, seed=7)
     assert base == [True, True]
-    monkeypatch.setenv("LTPU_MESH", "dp=8")
+    monkeypatch.setenv("LTPU_MESH", mesh)
     from lighthouse_tpu.crypto.tpu import sharding
 
     before = sharding.launch_counts()["sharded"]
@@ -143,7 +144,8 @@ def test_production_pipeline_sharded_verdicts_match(monkeypatch):
     assert sharding.launch_counts()["sharded"] == before + 2
 
 
-def test_production_per_set_poison_attribution_sharded(monkeypatch):
+@pytest.mark.parametrize("mesh", ["dp=4", "dp=8"])
+def test_production_per_set_poison_attribution_sharded(monkeypatch, mesh):
     """Poisoned-set attribution on a sharded batch: the per-set verdict
     vector is identical to the unsharded one, False exactly at the
     poisoned index."""
@@ -152,7 +154,7 @@ def test_production_per_set_poison_attribution_sharded(monkeypatch):
     sets = _oracle_sets(16, poison_at=poison)
     monkeypatch.delenv("LTPU_MESH", raising=False)
     base = tb.verify_signature_sets_per_set(sets)
-    monkeypatch.setenv("LTPU_MESH", "dp=8")
+    monkeypatch.setenv("LTPU_MESH", mesh)
     sharded = tb.verify_signature_sets_per_set(sets)
     want = [i != poison for i in range(len(sets))]
     assert base == want
@@ -184,3 +186,70 @@ def test_per_set_kernel_dp8_sharded(batch8x2):
     assert (got == ref).all()
     assert ref.all()
     assert bool(got_all) is True and bool(ref_all) is True
+
+
+def _one_pubkey_sets(n, foreign_at=None):
+    """n gossip-shaped sets, one pubkey each over its own message;
+    `foreign_at` carries the next set's signature instead of its own (a
+    point of G2, valid for another key and message)."""
+    rng = random.Random(31)
+    sets = []
+    for i in range(n):
+        sk = rng.randrange(1, 2**250)
+        msg = (1000 + i).to_bytes(32, "big")
+        sets.append(RB.SignatureSet(RB.sign(sk, msg), [RB.sk_to_pk(sk)], msg))
+    if foreign_at is not None:
+        s, other = sets[foreign_at], sets[(foreign_at + 1) % n]
+        sets[foreign_at] = RB.SignatureSet(other.signature, s.pubkeys,
+                                           s.message)
+    return sets
+
+
+class _NoHost:
+    """host_verifier that refuses: every verdict comes from the device
+    path."""
+
+    backend = "host"
+
+    def verify_signature_sets(self, sets, priority=None):
+        raise RuntimeError("verification was routed to the host path")
+
+    verify_signature_sets_per_set = verify_signature_sets
+
+
+def test_service_dp4_one_pubkey_requests_match_the_reference(monkeypatch):
+    """The four-chip gossip deployment's path at a small size: two
+    requests of 16 one-pubkey sets through VerificationService over
+    SignatureVerifier("tpu", fallback=False) under LTPU_MESH=dp=4, each
+    chunk of 8 sets split 2 to a device.  One request is clean; the
+    other carries a foreign signature in the last lane of shard 3 of its
+    second chunk.  Batch verdicts and per-set vectors equal crypto/ref's."""
+    from lighthouse_tpu.crypto import backend
+    from lighthouse_tpu.crypto.tpu import sharding
+    from lighthouse_tpu.verify_service import VerificationService
+
+    monkeypatch.setenv("LTPU_MAX_SETS_BUCKET", "8")
+    monkeypatch.setenv("LTPU_MESH", "dp=4")
+    monkeypatch.delenv("LTPU_MESH_DISABLE", raising=False)
+    # the CPU's virtual devices stand in for the host's four chips
+    monkeypatch.setattr(backend, "_device_platform", lambda: "tpu")
+    foreign = 8 + 7                     # chunk 1, lane 7: shard 3's last
+    requests = [_one_pubkey_sets(16), _one_pubkey_sets(16, foreign)]
+    svc = VerificationService(backend.SignatureVerifier("tpu",
+                                                        fallback=False),
+                              host_verifier=_NoHost())
+    assert svc.mesh_devices == 4
+    before = sharding.launch_counts()
+    try:
+        for sets in requests:
+            want = [RB.verify_signature_sets([s]) for s in sets]
+            assert svc.submit(sets).result(timeout=3600) is \
+                RB.verify_signature_sets(sets)
+            assert svc.submit(sets, want_per_set=True).result(
+                timeout=3600) == want
+    finally:
+        svc.stop()
+    assert want == [i != foreign for i in range(16)]
+    after = sharding.launch_counts()
+    assert after["sharded"] > before["sharded"]
+    assert after["single"] == before["single"]

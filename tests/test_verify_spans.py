@@ -1,8 +1,9 @@
 """Spans at the verify path's boundaries (utils/tracing.py live spans):
 `prep` per chunk staged, `prep_wait` wherever the dispatcher waits for
-one, `launch` per kernel call through CachedKernel, each on the batch's
-`verify_batch` trace with the enclosing span of its thread as `parent`,
-and each under a profiler annotation of the same name."""
+one, `place` per chunk's mesh placement, `launch` per kernel call
+through CachedKernel, each on the batch's `verify_batch` trace with the
+enclosing span of its thread as `parent`, and each under a profiler
+annotation of the same name."""
 
 import threading
 import time
@@ -246,3 +247,106 @@ def test_serial_path_prep_nests_in_prep_wait(monkeypatch, annotations):
     ]
     threads = {t for n, _, t in annotations}
     assert threads == {threading.current_thread().name}
+
+
+def _placeable(n, nlimb=2):
+    """A chunk's argument pytree at the ranks `prepare_chunk` stages
+    (pubkeys (limb, set, pk), signature and hash-to-field leaves
+    (limb, set), blinding scalars (2, set)), at a tiny limb count."""
+    def limbs():
+        return jnp.zeros((nlimb, n), jnp.int32)
+
+    pk = (jnp.zeros((nlimb, n, 1), jnp.int32),) * 3
+    sig = ((limbs(), limbs()),) * 3
+    return pk, sig, (limbs(), limbs()), (limbs(), limbs())
+
+
+class PlacingStub(KernelStub):
+    """Backend double over the real device stage: `execute` is
+    `bls.execute_chunk`, so each chunk goes through the mesh placement
+    and a CachedKernel launch (bls._jit_batched, patched to a probe)."""
+
+    def plan_pipeline(self, sets):
+        sets = list(sets)
+        chunks = [sets[i:i + self.chunk]
+                  for i in range(0, len(sets), self.chunk)]
+        index = {id(c): i for i, c in enumerate(chunks)}
+
+        def prepare(chunk):
+            c = bls.PreparedChunk()
+            c.chunk, c.invalid = index[id(chunk)], False
+            c.n_sets = c.n_pad = len(chunk)
+            c.args = _placeable(len(chunk)) + (
+                jnp.ones((2, len(chunk)), jnp.uint32),)
+            c.t_prep0 = c.t_prep1 = time.monotonic()
+            return c
+
+        return chunks, prepare, bls.execute_chunk
+
+
+@pytest.fixture
+def mesh_env(monkeypatch, request):
+    """LTPU_MESH=dp=4 on the 8 virtual CPU devices, or the mesh
+    disabled; the shards a placed chunk should report."""
+    monkeypatch.delenv("LTPU_MESH_DISABLE", raising=False)
+    monkeypatch.delenv("LTPU_MESH", raising=False)
+    if request.param == "dp=4":
+        monkeypatch.setenv("LTPU_MESH", "dp=4")
+        return 4
+    monkeypatch.setenv("LTPU_MESH_DISABLE", "1")
+    return 1
+
+
+@pytest.mark.parametrize("mesh_env", ["dp=4", "disabled"], indirect=True)
+def test_place_span_between_prep_wait_and_launch(kernel, monkeypatch,
+                                                 annotations, mesh_env):
+    probe = cc.CachedKernel(
+        "place_probe", lambda pk, sig, u0, u1, rands: jnp.all(rands > 0))
+    probe.registry = kernel.registry
+    monkeypatch.setattr(bls, "_jit_batched", probe)
+    tracing.clear()
+    sets = _sets(24)
+    svc = VerificationService(PlacingStub(kernel, chunk=8),
+                              target_batch=len(sets))
+    try:
+        assert svc.submit(sets).result(timeout=60.0) is True
+    finally:
+        svc.stop()
+    tr = _batch_trace(len(sets))
+    places = _named(tr, "place")
+    assert [s["attrs"]["chunk"] for s in places] == [0, 1, 2]
+    for s in places:
+        a = s["attrs"]
+        assert a["parent"] == "kernel" and a["per_set"] is False
+        assert a["shards"] == mesh_env
+        assert a["bytes"] > 0
+    waits = [s for s in _named(tr, "prep_wait") if not s["attrs"]["drain"]]
+    launches = _named(tr, "launch")
+    assert len(waits) == len(launches) == 3
+    for w, p, la in zip(waits, places, launches):
+        assert w["start_ms"] + w["duration_ms"] <= p["start_ms"] + 1e-3
+        assert p["start_ms"] + p["duration_ms"] <= la["start_ms"] + 1e-3
+    threads = {t for n, kw, t in annotations
+               if n == "place" and kw.get("trace_id") == tr["trace_id"]}
+    assert threads == {"verify_service"}
+
+
+@pytest.mark.parametrize("mesh_env", ["dp=4", "disabled"], indirect=True)
+def test_per_set_chunk_opens_a_place_span(kernel, monkeypatch, mesh_env):
+    probe = cc.CachedKernel(
+        "place_probe_per_set",
+        lambda pk, sig, u0, u1, real: (jnp.all(real), real))
+    probe.registry = kernel.registry
+    monkeypatch.setattr(bls, "_jit_per_set", probe)
+    monkeypatch.setattr(bls, "_prepare", lambda sets, dst, *a: (
+        sets, 8) + _placeable(8))
+    tr = tracing.start_trace("unit")
+    with tracing.use(tr):
+        got = bls._per_set_chunk([object()] * 6, None, chunk=2)
+    assert got == [True] * 6
+    spans = [(n, a) for n, _, _, a in tr.snapshot_spans()]
+    assert [n for n, _ in spans] == ["prep", "prep_wait", "place", "launch",
+                                     "device_chunk"]
+    place = spans[2][1]
+    assert place["chunk"] == 2 and place["per_set"] is True
+    assert place["shards"] == mesh_env and place["bytes"] > 0
