@@ -3,12 +3,17 @@
 the verification service, at mainnet widths.
 
     python chip_smoke.py              one chip: phases P0-P4
-    python chip_smoke.py --chips 4    four chips: P2 and P4 under the auto
-                                      mesh (dp=4) and under the single-device
-                                      plan (LTPU_MESH=1), compared
+    python chip_smoke.py --chips 4    four chips: P0, then P2 and P4 under
+                                      the auto mesh (dp=4) and under the
+                                      single-device plan (LTPU_MESH=1),
+                                      compared
 
 One chip:
   P0 device   JAX's default device, read in this process, is a TPU.
+  P0 mont_mul the base-field multiply on the device is exact: its
+              constant-operand dots against the host's convolution, and
+              mont_mul over extreme and random limbs against host
+              big-integer Montgomery products (phase_mont_mul).
   warm-up     the three verify programs: one 32x512 chunk and the 32x1
               per-set program in threads of their own, the 32x1 batched
               one in the node's prewarm (which also asks for the per-set
@@ -44,6 +49,8 @@ import os
 import sys
 import threading
 import time
+
+import numpy as np
 
 # The program set: every chunk pads to the 32-set bucket and every pubkey
 # axis to 1 or 512, so the verify programs are 32x1 batched, 32x1 per-set
@@ -183,6 +190,78 @@ class Guard:
         check(trips == 0, f"{phase}: a service breaker opened")
         check(host_built == 0, f"{phase}: a host verifier was built")
         check(not new, f"{phase}: compiled after warm-up: {new}")
+
+
+# ------------------------------------------------------ device arithmetic
+
+
+def _limb_kinds(rng):
+    """Lane contents for P0_mont_mul: (NLIMB,) int32 limbs at both ends
+    of mont_mul's admitted input range (|limbs| < 2^22), random inside
+    it, and canonical values."""
+    from lighthouse_tpu.crypto.constants import P
+    from lighthouse_tpu.crypto.tpu import fp
+
+    top = (1 << 22) - 1
+    n = fp.NLIMB
+    return [
+        np.full(n, top), np.full(n, -top),
+        np.where(np.arange(n) % 2 == 0, top, -top),
+        rng.integers(-top, top + 1, n), np.full(n, 255),
+        fp.int_to_limbs(P - 1), fp.int_to_limbs(int(rng.integers(1 << 62))),
+        np.zeros(n, np.int64),
+    ]
+
+
+def phase_mont_mul():
+    """P0_mont_mul: the device's Fp multiply is exact.  Its constant
+    products (`fp._mul_const_cols`, one f32 dot each) equal the host's
+    integer convolution at both ends of their admitted limb range, and
+    `fp.mont_mul` over extreme and random limbs equals the host's
+    big-integer Montgomery product, within its limb bounds.  A dot that
+    the device rounds shows here: the CPU's dots are exact at any
+    precision setting."""
+    import jax
+    import jax.numpy as jnp
+
+    from lighthouse_tpu.crypto.constants import P
+    from lighthouse_tpu.crypto.tpu import fp
+
+    t0 = time.monotonic()
+    rng = np.random.default_rng(SEED)
+    # constant products: lanes all -1024, all 1024, all -1, all 257
+    # (the ends of the admitted range, and of mont_mul's use of it), random
+    ends = [-1024, 1024, -1, 257]
+    x = np.concatenate([np.full((fp.NLIMB, len(ends)), ends),
+                        rng.integers(-1024, 1025, (fp.NLIMB, 60))], axis=1)
+    x = x.astype(np.int32)
+    for name, t, c in (("T_NP", fp.T_NP, fp.NPRIME_LIMBS),
+                       ("T_P", fp.T_P, fp.P_LIMBS)):
+        got = np.asarray(jax.jit(lambda v, t=t: fp._mul_const_cols(v, t))(
+            jnp.asarray(x)))
+        want = np.zeros(got.shape, np.int64)
+        for j in range(x.shape[1]):       # 2N-1 columns, then a zero
+            full = np.convolve(x[:, j].astype(np.int64), c.astype(np.int64))
+            want[:, j] = np.append(full, 0)[:len(want)]
+        bad = int((got != want).sum())
+        check(bad == 0, f"P0_mont_mul: {bad} wrong columns of x·{name}")
+    # mont_mul: every pair of lane kinds
+    kinds = _limb_kinds(rng)
+    a = np.stack([ka for ka in kinds for _ in kinds], axis=1)
+    b = np.stack([kb for _ in kinds for kb in kinds], axis=1)
+    a, b = a.astype(np.int32), b.astype(np.int32)
+    out = np.asarray(jax.jit(fp.mont_mul)(jnp.asarray(a), jnp.asarray(b)))
+    r_inv = pow(fp.R_INT, -1, P)
+    want = [x * y * r_inv % P for x, y in zip(fp.array_to_ints(a),
+                                              fp.array_to_ints(b))]
+    got = [v % P for v in fp.array_to_ints(out)]
+    wrong = sum(g != w for g, w in zip(got, want))
+    emit("P0_mont_mul", wall_s=round(time.monotonic() - t0, 3),
+         const_lanes=x.shape[1], mont_lanes=a.shape[1], wrong=wrong)
+    check(wrong == 0, f"P0_mont_mul: {wrong} of {a.shape[1]} products wrong")
+    check(out[:-1].min() >= -1 and out[:-1].max() <= 257
+          and out[-1].min() >= -3 and out[-1].max() <= 260,
+          "P0_mont_mul: output limbs out of bounds")
 
 
 # ------------------------------------------------------------------ data
@@ -601,6 +680,7 @@ def main(argv=None):
     profile.set_registry(profile.ProfileRegistry())   # this run's launches
     compiles = Compiles()
     try:
+        phase_mont_mul()
         if args.chips == 4:
             check(len(devices) == 4, f"--chips 4 sees {len(devices)} devices")
             run_four_chips(compiles)

@@ -23,14 +23,16 @@ Why this shape:
     stay far inside |limbs| < 2^22.
   * `add`/`sub`/`neg` are ONE elementwise HLO op each (round-2 cost:
     a 48-step `lax.scan` carry/borrow chain per call).  `mont_mul` costs
-    three shift-formulation column products and a handful of fold
-    passes, with NO sequential loop: in the exact division by R
-    (`_exact_div_R`) the folded low half's limbs below its top are worth
-    0 or 2^384, and one compare on limb 47 says which.  XLA compile time
-    for the pairing graph is linear in per-field-op HLO cost (measured in
-    round 3), so this representation is the second half of the
-    compile-cliff fix; on TPU every scan step is one iteration of a
-    device while loop, which is why the multiply has none.
+    one shift-formulation column product (a·b), two dots against
+    constant Toeplitz matrices (t·N′ and m·p, `_mul_const_cols`) and a
+    handful of fold passes, with NO sequential loop: in the exact
+    division by R (`_exact_div_R`) the folded low half's limbs below its
+    top are worth 0 or 2^384, and one compare on limb 47 says which.
+    XLA compile time for the pairing graph is linear in per-field-op
+    HLO cost (measured in round 3), so this representation is the
+    second half of the compile-cliff fix; on TPU every scan step is one
+    iteration of a device while loop, which is why the multiply has
+    none.
 
 Zero tests and equality are the only places full reduction happens, and
 the only carry scans (`_carry_scan`): `is_zero` compresses through one
@@ -412,6 +414,46 @@ _mul_cols = {
 }.get(_os.environ.get("LTPU_MULCOLS", "shift"), _mul_cols_shift)
 
 
+# ------------------------------------------- products by a constant
+#
+# For a host constant c the column sums are linear in x: cols = T_c · x
+# with the Toeplitz matrix T_c[k, j] = c_{k-j}.  Over the limb axis that
+# is one dot (49 values read a lane, n_out written), where the shift
+# form materialises and relayouts a (49, 145) outer product.  The dot
+# runs at Precision.HIGHEST, f32 arithmetic: on TPU the default rounds
+# f32 operands to bf16, which holds integers exactly only up to 256.
+# An explicit split x = 16·hi + lo against a bf16 [16·T_c | T_c] is
+# exact too, and ran no faster on a v5e (PERF.md).
+
+def _toeplitz(c_limbs, n_out):
+    """(n_out, N) f32 matrix T_c[k, j] = c_{k-j} (zero outside 0..N-1)."""
+    t = np.zeros((n_out, NLIMB), dtype=np.float32)
+    for k in range(n_out):
+        for j in range(max(0, k - NLIMB + 1), min(k, NLIMB - 1) + 1):
+            t[k, j] = c_limbs[k - j]
+    return t
+
+
+T_NP = _toeplitz(NPRIME_LIMBS, NLIMB)      # t·N′ truncated mod R
+T_P = _toeplitz(P_LIMBS, 2 * NLIMB)        # m·p, full width
+
+
+def _mul_const_cols(x, t):
+    """Column sums of x times the constant behind `t` (`T_NP`, `T_P`):
+    bit-identical to `_mul_cols_shift(x, c)` truncated to t's rows.
+
+    x: int32 (N, *batch), any batch rank, limbs in [-1024, 1024] — the
+    output range of `_compress_mod_R`, [-1, 257], lies inside it.  The
+    constants' limbs are in [0, 255], so a column's 49 products sum to
+    at most 49·255·1024 < 2^24 in magnitude: every partial sum is an
+    integer that f32 holds exactly, in any order of accumulation."""
+    cols = lax.dot_general(
+        t, x.astype(F32), (((1,), (0,)), ((), ())),
+        precision=lax.Precision.HIGHEST, preferred_element_type=F32,
+    )
+    return cols.astype(I32)
+
+
 # ---------------------------------------------------------------- public ops
 
 def add(a, b):
@@ -433,8 +475,9 @@ def mont_mul(a, b):
 
     Accepts lazily-reduced inputs (|limbs| < 2^22, any value); returns
     the integer u/R exactly: limbs below the top in [-1, 257], the top
-    limb in [-3, 260].  Cost: 2 compressions + 3 column products +
-    `_exact_div_R`'s four folds and one compare; no sequential loop.
+    limb in [-3, 260].  Cost: 2 compressions + the a·b column product +
+    2 constant-operand dots (`_mul_const_cols`) + `_exact_div_R`'s four
+    folds and one compare; no sequential loop.
 
     Correctness: the compressed operands a', b' have limbs in [-254, 510]
     and values in (-2·2^384, 258·2^384) (`_compress_limbs`), so every
@@ -451,10 +494,8 @@ def mont_mul(a, b):
     br = _compress_limbs(b)
     cols_t = _mul_cols(ar, br)                        # (2N, *batch) |.|<2^24
     t_red = _compress_mod_R(cols_t[:NLIMB])           # == t mod R
-    np_arr = jnp.asarray(NPRIME_LIMBS)[(...,) + (None,) * (cols_t.ndim - 1)]
-    m_red = _compress_mod_R(_mul_cols(t_red, np_arr, NLIMB))
-    p_arr = jnp.asarray(P_LIMBS)[(...,) + (None,) * (cols_t.ndim - 1)]
-    u = _mul_cols(m_red, p_arr) + cols_t              # ≡ 0 mod R
+    m_red = _compress_mod_R(_mul_const_cols(t_red, T_NP))
+    u = _mul_const_cols(m_red, T_P) + cols_t          # ≡ 0 mod R
     return _exact_div_R(u)
 
 

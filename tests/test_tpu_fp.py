@@ -307,3 +307,116 @@ def test_mont_mul_has_no_sequential_loop():
     x = jnp.zeros((fp.NLIMB, 4), jnp.int32)
     text = str(jax.make_jaxpr(fp.mont_mul)(x, x))
     assert "scan" not in text and "while" not in text
+
+
+# ------------------------------------- constant-operand products (dots)
+
+CONST_MATS = {"T_NP": (fp.T_NP, fp.NPRIME_LIMBS), "T_P": (fp.T_P, fp.P_LIMBS)}
+ADMITTED = 1024                     # |limbs| `_mul_const_cols` admits
+
+
+def const_operand(kind, batch):
+    """(NLIMB, *batch) int32 limbs for `_mul_const_cols`: all at the low
+    or the high end of its admitted range, random inside it, or random
+    over `_compress_mod_R`'s output range [-1, 257] (what mont_mul
+    passes) with both of its ends present."""
+    shape = (fp.NLIMB,) + batch
+    nrng = np.random.default_rng(len(batch) * 7 + len(kind))
+    if kind == "low_end":
+        return np.full(shape, -ADMITTED, np.int32)
+    if kind == "high_end":
+        return np.full(shape, ADMITTED, np.int32)
+    if kind == "random":
+        return nrng.integers(-ADMITTED, ADMITTED + 1, shape).astype(np.int32)
+    x = nrng.integers(-1, 258, shape).astype(np.int32)
+    flat = x.reshape(fp.NLIMB, -1)
+    flat[0, 0], flat[-1, -1] = -1, 257
+    return x
+
+
+@pytest.mark.parametrize("kind", ["low_end", "high_end", "random",
+                                  "mod_R_range"])
+@pytest.mark.parametrize("batch", [(), (5,), (3, 4), (2, 3, 2)])
+@pytest.mark.parametrize("mat", sorted(CONST_MATS))
+def test_mul_const_cols_matches_shift_product(mat, batch, kind):
+    """The Toeplitz dot equals the shift-form column sums bit for bit,
+    at batch ranks 0-3 and both ends of the admitted limb range."""
+    t, c = CONST_MATS[mat]
+    x = jnp.asarray(const_operand(kind, batch))
+    c_b = jnp.asarray(c)[(...,) + (None,) * len(batch)]
+    got = np.asarray(fp._mul_const_cols(x, t))
+    want = np.asarray(fp._mul_cols_shift(x, c_b, t.shape[0]))
+    assert got.dtype == want.dtype == np.int32
+    assert got.shape == want.shape == (t.shape[0],) + batch
+    assert (got == want).all()
+
+
+def test_toeplitz_constants_are_the_limb_products():
+    """T_c · x is the integer product c·x read in limbs: its columns
+    below R for T_NP (value ≡ c·x mod R), all of them for T_P."""
+    x = fp.int_to_limbs(rng.randrange(fp.R_INT)).astype(np.int64)
+    want = {m: fp.limbs_to_int(c) * fp.limbs_to_int(x)
+            for m, (_, c) in CONST_MATS.items()}
+    values = {m: sum(int(v) << (8 * k) for k, v in
+                     enumerate(t.astype(np.int64) @ x))
+              for m, (t, _) in CONST_MATS.items()}
+    assert values["T_P"] == want["T_P"]
+    assert values["T_NP"] % fp.R_INT == want["T_NP"] % fp.R_INT
+
+
+LIMB_TOP = 2 ** 22 - 1
+
+
+def extreme_limbs(kind, n):
+    """(NLIMB, n) int32 mont_mul operands at the ends of its admitted
+    input range (|limbs| < 2^22) and inside it."""
+    shape = (fp.NLIMB, n)
+    nrng = np.random.default_rng(n + len(kind))
+    if kind == "max":
+        return np.full(shape, LIMB_TOP, np.int32)
+    if kind == "min":
+        return np.full(shape, -LIMB_TOP, np.int32)
+    if kind == "alternating":
+        signs = np.where(np.arange(fp.NLIMB) % 2 == 0, 1, -1)[:, None]
+        return np.broadcast_to(signs * LIMB_TOP, shape).astype(np.int32)
+    if kind == "random":
+        return nrng.integers(-LIMB_TOP, LIMB_TOP + 1, shape).astype(np.int32)
+    return fp.ints_to_array(rand_fp(n))                       # canonical
+
+
+LIMB_KINDS = ["max", "min", "alternating", "random", "canonical"]
+
+
+@pytest.mark.parametrize("kind_a", LIMB_KINDS)
+def test_mont_mul_matches_bigint_at_extremes(kind_a):
+    """mont_mul of extreme operands equals the host's big-integer
+    Montgomery product (mod p, and exactly the quotient u/R), every kind
+    of a against every kind of b, within the output limb bounds."""
+    n = 4
+    a = np.concatenate([extreme_limbs(kind_a, n)] * len(LIMB_KINDS), axis=1)
+    b = np.concatenate([extreme_limbs(k, n) for k in LIMB_KINDS], axis=1)
+    a, b = jnp.asarray(a), jnp.asarray(b)
+    out = fp.mont_mul(a, b)
+    assert_mont_output(out, fp.array_to_ints(np.asarray(a)),
+                       fp.array_to_ints(np.asarray(b)))
+    assert fp.array_to_ints(np.asarray(out)) == exact_quotient(a, b)
+
+
+def test_mont_mul_structure_two_constant_dots_one_shift_product():
+    """mont_mul's constant products are two dots against the Toeplitz
+    constants, at HIGHEST precision, and its a·b product is the one
+    shift-form product (a single diagonal reduce_sum)."""
+    import jax
+
+    x = jnp.zeros((fp.NLIMB, 4), jnp.int32)
+    closed = jax.make_jaxpr(fp.mont_mul)(x, x)
+    consts = dict(zip(closed.jaxpr.constvars, closed.consts))
+    prims = [e.primitive.name for e in closed.jaxpr.eqns]
+    dots = [e for e in closed.jaxpr.eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == 2
+    lhs = [np.asarray(consts[d.invars[0]]) for d in dots]
+    assert lhs[0].shape == fp.T_NP.shape and (lhs[0] == fp.T_NP).all()
+    assert lhs[1].shape == fp.T_P.shape and (lhs[1] == fp.T_P).all()
+    for d in dots:
+        assert d.params["precision"] == (jax.lax.Precision.HIGHEST,) * 2
+    assert prims.count("reduce_sum") == 1

@@ -16,6 +16,13 @@ program depends on shapes only).  Pure host-side tracing — no TPU.
   length NLIMB are `fp._carry_scan`'s (is_zero/canonical); length 2N was
   mont_mul's carry scan before it was made scan-free; any other length is
   an outer loop (Miller loop, exponentiations, ladders).
+- Column products, by form, in the same walk: `shift` is the
+  `fp._mul_cols_shift` product (its one diagonal `reduce_sum` over a
+  (N, 2N-1, *batch) operand), `const_dot` a `fp._mul_const_cols` dot
+  against a Toeplitz constant (T_NP or T_P).  Each is counted executed
+  and lane-weighted (lanes = its batch size); every mont_mul does two
+  constant dots over its own lanes, so half of their counts are the
+  executed mont_muls and their lane-weighted mults.
 
 Usage: python tools/count_kernel_mults.py [sets pks]...
 """
@@ -66,11 +73,29 @@ def _sub_jaxprs(eqn):
                 yield x
 
 
+def _column_product(eqn):
+    """('shift' | 'const_dot', lanes) when `eqn` is a column product of
+    mont_mul's, else None."""
+    name = eqn.primitive.name
+    if name == "dot_general":
+        lhs = eqn.invars[0].aval
+        if lhs.shape in (fp.T_NP.shape, fp.T_P.shape) and \
+                lhs.dtype == fp.T_P.dtype:
+            return "const_dot", int(np.prod(eqn.outvars[0].aval.shape[1:]))
+    elif name == "reduce_sum" and eqn.params["axes"] == (0,):
+        shape = eqn.invars[0].aval.shape
+        if shape[:2] == (fp.NLIMB, 2 * fp.NLIMB - 1):
+            return "shift", int(np.prod(shape[2:]))
+    return None
+
+
 def loop_steps(jaxpr):
-    """Executed loop work of one launch: ({scan length: [scans run, steps]},
-    equations executed, while loops met).  A while loop's trip count is
+    """Executed work of one launch: ({scan length: [scans run, steps]},
+    equations executed, while loops met, {column-product form:
+    [products run, lane-weighted]}).  A while loop's trip count is
     unknown at trace time: its body counts once and it is reported."""
     by_len = collections.defaultdict(lambda: [0, 0])
+    products = collections.defaultdict(lambda: [0, 0])
     totals = {"eqns": 0, "whiles": 0}
 
     def walk(j, mult):
@@ -84,11 +109,15 @@ def loop_steps(jaxpr):
                 inner = mult * n
             elif eqn.primitive.name == "while":
                 totals["whiles"] += mult
+            product = _column_product(eqn)
+            if product:
+                products[product[0]][0] += mult
+                products[product[0]][1] += mult * product[1]
             for sub in _sub_jaxprs(eqn):
                 walk(sub, inner)
 
     walk(jaxpr, 1)
-    return dict(by_len), totals["eqns"], totals["whiles"]
+    return dict(by_len), totals["eqns"], totals["whiles"], dict(products)
 
 
 def count(n_sets, pks):
@@ -102,7 +131,7 @@ def report(n, m):
     mc, jaxpr = count(n, m)
     print(f"sets={n} pks={m}: traced mont_mul instances={mc.instances} "
           f"lane-weighted mults={mc.mults} per-set={mc.mults / n:.0f}")
-    by_len, eqns, whiles = loop_steps(jaxpr)
+    by_len, eqns, whiles, products = loop_steps(jaxpr)
     kinds = {fp.NLIMB: "carry (is_zero/canonical)",
              2 * fp.NLIMB: "carry (mont_mul)"}
     total = sum(s for _, s in by_len.values())
@@ -112,6 +141,14 @@ def report(n, m):
         runs, steps = by_len[length]
         print(f"  scan length {length:>4}: {runs:>8} runs {steps:>9} steps"
               f"  {kinds.get(length, 'outer')}")
+    dots = products.get("const_dot", [0, 0])
+    print(f"  executed mont_mul={dots[0] // 2} lane-weighted mults="
+          f"{dots[1] // 2} per-set={dots[1] / 2 / n:.0f}")
+    n_all = sum(runs for runs, _ in products.values())
+    for form in sorted(products):
+        runs, lanes = products[form]
+        print(f"  column products {form:>9}: {runs:>8} executed "
+              f"{lanes:>10} lane-weighted ({100 * runs / n_all:.1f} %)")
 
 
 if __name__ == "__main__":
