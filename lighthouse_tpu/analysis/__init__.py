@@ -19,8 +19,7 @@ Layout:
 - ``waivers.json`` — the machine-readable waiver ledger
 
 Entrypoints: ``tools/lint.py`` (CLI, nonzero exit on unwaived
-findings), ``tests/test_analysis.py`` (tier-1 wiring), and the
-``bench.py`` preflight.
+findings) and ``tests/test_analysis.py`` (tier-1 wiring).
 """
 
 from .core import (  # noqa: F401

@@ -16,7 +16,7 @@ refactors honest:
   planner replaced must not creep back in
 - ``jnp.pad`` / ``np.pad`` sites in ``crypto/tpu/`` are flagged:
   batch padding is the planner's job; kernel-internal lane alignment
-  (fp/pallas limb padding) is waivered with that justification
+  (fp limb padding) is waivered with that justification
 """
 
 import ast

@@ -1,8 +1,8 @@
 """Compile-lifecycle subsystem: canonical shapes + persistent AOT cache.
 
 The device path's dominant cost is no longer the kernel — it is XLA
-compilation: 42-132 s warm per bucket shape, up to 314 s cold
-(BENCH_WARM.json).  Every watchdog restart or fresh verifier host used
+compilation: minutes per bucket shape for the verify programs on a
+v5e (PERF.md).  Every watchdog restart or fresh verifier host used
 to pay that again, mid-slot.  This module kills the tax in three moves:
 
   1. **ShapePlanner** — every `(n_sets, max_pks)` batch lands on a shape
@@ -108,7 +108,8 @@ class ShapePlanner:
     """Total map from a requested batch shape onto the canonical menu.
 
     * set axis: menu defaults to the pow-2 ladder up to the compile
-      bucket (`LTPU_MAX_SETS_BUCKET`, default 32 — the BENCH_r05 knee);
+      bucket (`LTPU_MAX_SETS_BUCKET`, default 32, the set width of the
+      verify programs the chip benchmark measures);
       batches beyond the bucket are CHUNKED by the caller, so the axis
       never exceeds the menu top.
     * pubkey axis: pow-2 ladder up to `LTPU_SHAPE_MAX_PKS` (default

@@ -19,9 +19,8 @@ tested against the oracle).
 
 Backend economics (measured): on the CPU backend the five fixed-exponent
 pow scans LOSE to host Python (3.1 ms/sig host vs ~119 ms/sig device at
-batch 256 on one core) — this kernel is a TPU capability; bench.py's
-kernel_candidates section times it per platform so the deployment choice
-is made from measurements, not guesses.
+batch 256 on one core) — this kernel is a TPU capability, and whether
+a deployment uses it is a choice to make from a chip measurement.
 """
 
 import numpy as np

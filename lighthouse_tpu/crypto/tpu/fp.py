@@ -302,9 +302,6 @@ def _exact_div_R(u):
     c = (top + [S = 2^384]) / 256 exactly, and c joins the high half's
     limb 0 (|c| < 2^16, so |u| + |c| stays < 2^24)."""
     low = _compress_keep(u[:NLIMB])
-    # (1, *batch) row slices, not int indices: the Pallas kernel
-    # (pallas_fp) runs this function too, and Mosaic lowers static
-    # slices only
     spill = (low[-2:-1] > (1 << (LB - 1))).astype(I32)
     carry = (low[-1:] + spill) >> LB
     high = jnp.concatenate([u[NLIMB:NLIMB + 1] + carry, u[NLIMB + 1:]], axis=0)
@@ -318,7 +315,7 @@ def _exact_div_R(u):
 compress = _compress_limbs
 
 
-# ------------------------------------------------- column-sum candidates
+# ------------------------------------------------------ column sums
 
 def _mul_cols_shift(a, b, n_out=2 * NLIMB):
     """Schoolbook column sums via diagonal-sum reshape — no einsum, no
@@ -347,71 +344,6 @@ def _mul_cols_shift(a, b, n_out=2 * NLIMB):
             [cols, jnp.zeros((n_out - cols.shape[0],) + bshape, F32)], axis=0
         )
     return cols[:n_out].astype(I32)
-
-
-# Constant antidiagonal-gather matrix for the einsum candidates (kept for
-# the bench kernel shoot-out; the shift path is the default).
-def _diag_mat():
-    m = np.zeros((2 * NLIMB, NLIMB * NLIMB), dtype=np.float32)
-    for i in range(NLIMB):
-        for j in range(NLIMB):
-            m[i + j, i * NLIMB + j] = 1.0
-    return m
-
-
-_DIAG_MAT = None
-
-
-def _mul_cols_f32(a, b, n_out=2 * NLIMB):
-    """einsum candidate: one f32 GEMM against a constant 0/1 gather
-    matrix (HIGHEST precision is load-bearing on TPU — default bf16
-    passes would corrupt the 16-bit limb products)."""
-    global _DIAG_MAT
-    if _DIAG_MAT is None:
-        _DIAG_MAT = _diag_mat()
-    bshape = _bshape(a, b)
-    af = a.astype(F32)
-    bf = b.astype(F32)
-    prods = (af[:, None] * bf[None, :]).reshape((NLIMB * NLIMB,) + bshape)
-    cols = jnp.einsum(
-        "ks,s...->k...",
-        jnp.asarray(_DIAG_MAT[:n_out]),
-        prods,
-        preferred_element_type=F32,
-        precision=lax.Precision.HIGHEST,
-    )
-    return cols.astype(I32)
-
-
-_DIAG_MAT_I32 = None
-
-
-def _mul_cols_int32(a, b, n_out=2 * NLIMB):
-    """int32-dot candidate (whether XLA puts it on the MXU is a per-
-    backend measurement; bench.py answers it)."""
-    global _DIAG_MAT_I32
-    if _DIAG_MAT_I32 is None:
-        _DIAG_MAT_I32 = _diag_mat().astype(np.int32)
-    bshape = _bshape(a, b)
-    ai = a.astype(jnp.int32)
-    bi = b.astype(jnp.int32)
-    prods = (ai[:, None] * bi[None, :]).reshape((NLIMB * NLIMB,) + bshape)
-    cols = jnp.einsum(
-        "ks,s...->k...",
-        jnp.asarray(_DIAG_MAT_I32[:n_out]),
-        prods,
-        preferred_element_type=jnp.int32,
-    )
-    return cols.astype(I32)
-
-
-import os as _os
-
-_mul_cols = {
-    "int32": _mul_cols_int32,
-    "einsum": _mul_cols_f32,
-    "f32": _mul_cols_f32,
-}.get(_os.environ.get("LTPU_MULCOLS", "shift"), _mul_cols_shift)
 
 
 # ------------------------------------------- products by a constant
@@ -492,7 +424,7 @@ def mont_mul(a, b):
     """
     ar = _compress_limbs(a)
     br = _compress_limbs(b)
-    cols_t = _mul_cols(ar, br)                        # (2N, *batch) |.|<2^24
+    cols_t = _mul_cols_shift(ar, br)                  # (2N, *batch) |.|<2^24
     t_red = _compress_mod_R(cols_t[:NLIMB])           # == t mod R
     m_red = _compress_mod_R(_mul_const_cols(t_red, T_NP))
     u = _mul_const_cols(m_red, T_P) + cols_t          # ≡ 0 mod R

@@ -13,8 +13,7 @@ The registry persists beside the AOT compile cache
 (`<cache_dir>/kernel_profile.json`, atomic tmp+replace, throttled) so
 cold-start wall/cost baselines survive process restarts the way the
 executables themselves do.  Served at `GET /lighthouse/profile`;
-summarized by `tools/profile_report.py`; recorded by bench.py into
-BENCH_PRIMARY.json under `kernel_profile`.
+summarized by `tools/profile_report.py`.
 
 Measurement notes: wall times include `block_until_ready`, so they are
 device wall, not dispatch wall.  cost_analysis is XLA's static model —
@@ -223,36 +222,6 @@ class ProfileRegistry:
             "topology": _topology(),
             "launch_counts": launch_counts,
             "rows": self.rows(),
-        }
-
-    def summary(self, top_n=5):
-        """Compact roll-up for BENCH_PRIMARY.json: per-kernel totals
-        and the top-N wall-time sinks."""
-        rows = self.rows()
-        per_kernel = {}
-        for e in rows:
-            k = per_kernel.setdefault(e["kernel"], {
-                "launches": 0, "total_ms": 0.0, "shapes": 0,
-            })
-            k["launches"] += e["launches"]
-            k["total_ms"] = round(k["total_ms"] + e["total_ms"], 3)
-            k["shapes"] += 1
-        top = [
-            {
-                "kernel": e["kernel"], "shape": e["shape"],
-                "topology": e["topology"], "total_ms": e["total_ms"],
-                "launches": e["launches"], "ewma_ms": e["ewma_ms"],
-                **({"flops": e["cost"].get("flops")} if e["cost"] else {}),
-            }
-            for e in rows[:top_n]
-        ]
-        snap = self.snapshot()
-        return {
-            "schema": _SCHEMA,
-            "topology": snap["topology"],
-            "launch_counts": snap["launch_counts"],
-            "kernels": per_kernel,
-            "top_sinks": top,
         }
 
     def reset(self):

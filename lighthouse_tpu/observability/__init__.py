@@ -20,9 +20,8 @@ Three coupled pieces, each its own module:
 
 Surfaces: ``GET /lighthouse/state-profile``, ``GET
 /lighthouse/forkchoice``, the ``state_profile`` /
-``forkchoice_forensics`` incident-bundle sections, and the
-``epoch_profile`` key bench.py merges into BENCH_SCALE.json — the
-BEFORE baseline for the ROADMAP epoch-on-device item.
+``forkchoice_forensics`` incident-bundle sections — the BEFORE
+baseline for the ROADMAP epoch-on-device item.
 """
 
 from . import forkchoice_forensics, stage_profile, state_diff

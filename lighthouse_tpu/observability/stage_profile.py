@@ -23,8 +23,7 @@ row so stage totality (stages sum ~= epoch wall) is checkable from the
 registry alone.
 
 Served at ``GET /lighthouse/state-profile``; summarized by
-``tools/profile_report.py --state``; recorded by the ``bench.py
-config_epoch_profile`` lane into BENCH_SCALE.json.
+``tools/profile_report.py --state``.
 """
 
 import json
@@ -275,8 +274,8 @@ class StageProfileRegistry:
 
     def stage_totals(self):
         """{stage: {total_ms, calls, ops}} aggregated over fork and
-        vbucket — the bench lane's per-stage table and the totality
-        check's numerator."""
+        vbucket — the per-stage table and the totality check's
+        numerator."""
         out = {}
         for e in self.rows():
             s = out.setdefault(e["stage"], {

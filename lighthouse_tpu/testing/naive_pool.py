@@ -4,9 +4,8 @@ This is the OLD `OperationPool.insert_attestation` path verbatim: a host
 G2 decompress → point-add → compress round-trip per insert, Python-list
 bitset loops, no validation.  It exists as (a) the differential oracle
 for the aggregation tier's byte-identity property tests and (b) the
-per-insert host-aggregation baseline that `tools/scale_bench.py`
-measures `agg_inserts_per_sec` against.  Do not "fix" it — its value is
-being exactly what the tier replaced.
+per-insert host-aggregation baseline the tier is compared against.
+Do not "fix" it — its value is being exactly what the tier replaced.
 """
 
 from collections import defaultdict

@@ -144,129 +144,6 @@ def make_scaled_state(n_validators, spec, epoch=4, participation=0.99, seed=0,
     return state
 
 
-def make_epoch_traffic(state, spec, head_root, *, aggregates_per_committee=2,
-                       singles_per_committee=2, sync_slots=2, seed=0,
-                       sig_pool=None):
-    """Synthesize a full epoch of gossip-shaped traffic for the state's
-    current epoch: SignedAggregateAndProof batches (selection proofs
-    drawn from the valid-point pool so `_is_aggregator` passes),
-    unaggregated single-bit attestations from distinct validators (the
-    chain's observed-attester dedup admits each validator once per
-    epoch), and — on an Altair state — sync-committee messages for the
-    current committee.
-
-    Every signature/proof is a valid compressed G2 point from
-    `sig_pool`; `beacon_block_root`/`target.root` are `head_root` (the
-    only block fork choice knows on a fresh chain).  Returns
-    {"aggregates", "attestations", "sync_messages"}."""
-    import hashlib
-
-    from ..types.containers import (
-        AggregateAndProof,
-        SignedAggregateAndProof,
-        SyncCommitteeMessage,
-    )
-
-    preset = spec.preset
-    T = state_types(preset)
-    rng = np.random.default_rng(seed)
-    head_root = bytes(head_root)
-    epoch = int(state.slot) // preset.slots_per_epoch
-    cache = committees_for_epoch(state, epoch, preset)
-    target = Checkpoint(epoch=epoch, root=head_root)
-    source = state.current_justified_checkpoint
-    if sig_pool is None:
-        sig_pool = make_signature_pool(256)
-
-    proof_of = {}          # is_aggregator modulo -> passing proof
-
-    def proof_for(committee_len):
-        modulo = max(1, committee_len // 16)
-        if modulo not in proof_of:
-            proof_of[modulo] = next(
-                (
-                    cand for cand in sig_pool
-                    if int.from_bytes(
-                        hashlib.sha256(cand).digest()[:8], "little"
-                    ) % modulo == 0
-                ),
-                sig_pool[0],
-            )
-        return proof_of[modulo]
-
-    aggregates, singles = [], []
-    used_aggregators, used_attesters = set(), set()
-    si = 0
-    for slot in range(epoch * preset.slots_per_epoch,
-                      (epoch + 1) * preset.slots_per_epoch):
-        for index in range(cache.committees_per_slot):
-            committee = cache.committee(slot, index)
-            clen = len(committee)
-            data = AttestationData(
-                slot=slot, index=index, beacon_block_root=head_root,
-                source=source, target=target,
-            )
-            fresh = [int(v) for v in committee if int(v) not in used_aggregators]
-            for j in range(min(aggregates_per_committee, len(fresh))):
-                bits = (rng.random(clen) < 0.75).astype(int).tolist()
-                bits[j % clen] = 1
-                used_aggregators.add(fresh[j])
-                aggregates.append(SignedAggregateAndProof(
-                    message=AggregateAndProof(
-                        aggregator_index=fresh[j],
-                        aggregate=T.Attestation(
-                            aggregation_bits=bits, data=data,
-                            signature=sig_pool[si % len(sig_pool)],
-                        ),
-                        selection_proof=proof_for(clen),
-                    ),
-                    signature=sig_pool[(si + 1) % len(sig_pool)],
-                ))
-                si += 1
-            picked = 0
-            for pos in range(clen):
-                if picked == singles_per_committee:
-                    break
-                if int(committee[pos]) in used_attesters:
-                    continue
-                used_attesters.add(int(committee[pos]))
-                bits = [0] * clen
-                bits[pos] = 1
-                singles.append(T.Attestation(
-                    aggregation_bits=bits, data=data,
-                    signature=sig_pool[si % len(sig_pool)],
-                ))
-                si += 1
-                picked += 1
-
-    sync_messages = []
-    if hasattr(state, "current_sync_committee"):
-        from ..state_processing import altair
-
-        committee_indices = altair.sync_committee_validator_indices(
-            state, preset
-        )
-        base_slot = int(state.slot)
-        for off in range(sync_slots):
-            seen = set()
-            for vi in committee_indices:
-                vi = int(vi)
-                if vi in seen:
-                    continue
-                seen.add(vi)
-                sync_messages.append(SyncCommitteeMessage(
-                    slot=base_slot + off, beacon_block_root=head_root,
-                    validator_index=vi,
-                    signature=sig_pool[si % len(sig_pool)],
-                ))
-                si += 1
-    return {
-        "aggregates": aggregates,
-        "attestations": singles,
-        "sync_messages": sync_messages,
-    }
-
-
 class _NewValidator:
     """Attribute bag matching the `Validator` container surface the
     registry's append() reads — the churn helper's deposit shape."""

@@ -2,7 +2,7 @@
 
 `scale.py` builds one epoch of load against a frozen head; production is
 hours of churn, reorgs, and sync racing live import.  This module adds
-the continuation pieces the soak driver (tools/soak_bench.py) composes:
+the continuation pieces a multi-epoch soak composes:
 
   * `produce_block` / `attest_branch` — real block production and
     full-committee branch votes on SCALED states, with every signature a
@@ -21,9 +21,9 @@ the continuation pieces the soak driver (tools/soak_bench.py) composes:
     check at the end;
   * `FleetHarness` — fleet mode (ISSUE 20): one logical verification
     plane sharded over a coordinator + K fault-isolated ShardWorkers,
-    with kill / restart-from-persist / re-join helpers, so the soak
-    driver, the simulator chaos scenarios and the bench all build the
-    same fleet the same way.
+    with kill / restart-from-persist / re-join helpers, so the
+    simulator's chaos scenarios and the tests build the same fleet the
+    same way.
 
 The rig requires the chain's default `MemoryStore` (churn mutates the
 stored head state in place — a serializing store would snapshot it).

@@ -270,7 +270,7 @@ class VerificationService:
                  max_delay=None, queue_caps=None,
                  breaker_threshold=3, breaker_cooldown=30.0,
                  breaker_probe_max=None,
-                 shed_watermark=None, pipeline=True,
+                 shed_watermark=None,
                  adaptive_batch=False, target_bounds=None,
                  remote_pool=None, mesh_devices=None):
         self.verifier = verifier or SignatureVerifier("oracle")
@@ -295,9 +295,6 @@ class VerificationService:
         self.max_batch = max(
             int(max_batch) * self.mesh_devices, self.target_batch
         )
-        # two-stage host-prep/device pipeline for multi-chunk batches
-        # (engages only when the backend exposes a plan_pipeline split)
-        self.pipeline = bool(pipeline)
         # adaptive dispatch threshold: walk target_batch toward the
         # measured fixed-cost/marginal-cost knee instead of pinning the
         # constructor constant.  Opt-in: latency-sensitive tests (and
@@ -393,8 +390,8 @@ class VerificationService:
         if hasattr(self.verifier, "on_device_fallback"):
             self.verifier.on_device_fallback = self._note_device_failure
 
-        # bounded observability windows (tools/verify_service_bench.py and
-        # tests read these; Prometheus carries the unbounded series)
+        # bounded observability windows (tests and the service's own
+        # stats read these; Prometheus carries the unbounded series)
         self.dispatched_batches = deque(maxlen=4096)   # sets per batch
         self.recent_waits = deque(maxlen=8192)         # queue wait seconds
         self.recent_overlaps = deque(maxlen=4096)      # pipelined prep overlap
@@ -957,22 +954,21 @@ class VerificationService:
         multiple chunks; the plain call otherwise.  A pipeline failure
         falls back to the plain call, whose internal degrade chain owns
         device-failure semantics (breaker events included)."""
-        if self.pipeline:
-            plan_fn = getattr(v, "plan_pipeline", None)
-            plan = None
-            if plan_fn is not None:
-                try:
-                    plan = plan_fn(all_sets)
-                except Exception:
-                    plan = None
-            if plan:
-                try:
-                    return self._run_pipeline(*plan)
-                except Exception as e:
-                    log.warning(
-                        "pipelined dispatch failed (%s); plain path",
-                        str(e)[:200],
-                    )
+        plan_fn = getattr(v, "plan_pipeline", None)
+        plan = None
+        if plan_fn is not None:
+            try:
+                plan = plan_fn(all_sets)
+            except Exception:
+                plan = None
+        if plan:
+            try:
+                return self._run_pipeline(*plan)
+            except Exception as e:
+                log.warning(
+                    "pipelined dispatch failed (%s); plain path",
+                    str(e)[:200],
+                )
         return v.verify_signature_sets(all_sets)
 
     def _verify_probe_split(self, all_sets, cap):

@@ -88,10 +88,6 @@ def test_snapshot_and_summary_shape(reg):
     assert set(snap) >= {"schema", "topology", "launch_counts", "rows"}
     # rows sorted by total wall, heaviest first
     assert [r["kernel"] for r in snap["rows"]] == ["a", "b"]
-    summary = reg.summary(top_n=1)
-    assert summary["kernels"]["a"]["launches"] == 1
-    assert len(summary["top_sinks"]) == 1
-    assert summary["top_sinks"][0]["kernel"] == "a"
 
 
 def test_extract_cost_tolerates_shapes():

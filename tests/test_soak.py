@@ -1,4 +1,4 @@
-"""Multi-epoch soak rig unit tier (non-slow mirror of tools/soak_bench.py).
+"""Multi-epoch soak rig unit tier (`testing/soak.py` at small N).
 
 Covers the continuation seams the soak composes, at small N so the whole
 file runs in seconds:

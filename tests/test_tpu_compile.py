@@ -17,7 +17,7 @@ import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from lighthouse_tpu.crypto.tpu import bls as tb
-from lighthouse_tpu.crypto.tpu import fp, pallas_fp, sharding
+from lighthouse_tpu.crypto.tpu import fp, sharding
 
 
 @pytest.fixture(scope="module")
@@ -66,22 +66,6 @@ def test_mont_mul_compiles_for_v5e(one_chip, no_persistent_cache):
     x = _limbs(65536, one_chip)
     compiled = jax.jit(fp.mont_mul).lower(x, x).compile()
     assert compiled.memory_analysis() is not None
-
-
-@pytest.mark.parametrize("kernel", ["mont_mul_pallas", "mont_chain_pallas"])
-def test_pallas_mont_mul_lowers_through_mosaic(kernel, one_chip,
-                                               no_persistent_cache):
-    """The fused Pallas kernels lower for the chip (interpret mode proves
-    their values in tests/test_pallas_fp.py)."""
-    if kernel == "mont_mul_pallas":
-        def f(a, b):
-            return pallas_fp.mont_mul_pallas(a, b)
-    else:
-        def f(a, b):
-            return pallas_fp.mont_chain_pallas(a, b, 4)
-    x = _limbs(4 * pallas_fp.TILE, one_chip)
-    compiled = jax.jit(f).lower(x, x).compile()
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.slow

@@ -177,8 +177,8 @@ def exact_quotient(a, b):
     b' the compressed operands and m the Montgomery quotient in the limb
     form mont_mul builds, all read back as Python integers."""
     ar, br = fp._compress_limbs(a), fp._compress_limbs(b)
-    t_red = fp._compress_mod_R(fp._mul_cols(ar, br)[:fp.NLIMB])
-    m_red = fp._compress_mod_R(fp._mul_cols(
+    t_red = fp._compress_mod_R(fp._mul_cols_shift(ar, br)[:fp.NLIMB])
+    m_red = fp._compress_mod_R(fp._mul_cols_shift(
         t_red, jnp.asarray(fp.NPRIME_LIMBS)[:, None], fp.NLIMB))
     ts = [x * y for x, y in zip(fp.array_to_ints(np.asarray(ar)),
                                 fp.array_to_ints(np.asarray(br)))]
@@ -400,6 +400,38 @@ def test_mont_mul_matches_bigint_at_extremes(kind_a):
     assert_mont_output(out, fp.array_to_ints(np.asarray(a)),
                        fp.array_to_ints(np.asarray(b)))
     assert fp.array_to_ints(np.asarray(out)) == exact_quotient(a, b)
+
+
+# flat batches, and the batch shapes verify programs multiply at on the
+# chip: (3, 3, 32) and (3, 2, 64) in the gossip program, (4, 32, 256),
+# and the block program's lane-dense (32, 512)
+KERNEL_BATCH_SHAPES = [(1,), (7,), (256,), (261,),
+                       (3, 3, 32), (3, 2, 64), (4, 32, 256), (32, 512)]
+
+
+@pytest.mark.parametrize("batch", KERNEL_BATCH_SHAPES,
+                         ids=lambda b: "x".join(map(str, b)))
+def test_mont_mul_matches_bigint_at_kernel_batch_shapes(batch):
+    """mont_mul over a multi-axis batch equals the host's big-integer
+    Montgomery product a·b·R^-1 mod p in every element, within the
+    output limb bounds: canonical operands in even columns of the last
+    axis, lazily reduced ones (signed limbs up to 2^21) in odd ones."""
+    import jax
+
+    n = int(np.prod(batch))
+    nrng = np.random.default_rng(n)
+
+    def operand():
+        canon = fp.ints_to_array(rand_fp(n))
+        lazy = nrng.integers(-2 ** 21, 2 ** 21, (fp.NLIMB, n), np.int32)
+        odd = np.arange(n) % batch[-1] % 2 == 1
+        return np.where(odd, lazy, canon).reshape((fp.NLIMB,) + batch)
+
+    a, b = operand(), operand()
+    out = jax.jit(fp.mont_mul)(jnp.asarray(a), jnp.asarray(b))
+    assert out.shape == (fp.NLIMB,) + batch
+    assert_mont_output(out.reshape(fp.NLIMB, n), fp.array_to_ints(a),
+                       fp.array_to_ints(b))
 
 
 def test_mont_mul_structure_two_constant_dots_one_shift_product():

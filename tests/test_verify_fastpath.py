@@ -270,7 +270,6 @@ def test_adaptive_service_walks_target_batch():
 
     service = VerificationService(
         CostModel(), target_batch=512, max_batch=512, adaptive_batch=True,
-        pipeline=False,
     )
     rng = random.Random(3)
     for _ in range(60):
@@ -328,7 +327,7 @@ def test_deadline_heap_bounded_under_sustained_load():
         def verify_signature_sets_per_set(self, sets, priority=None):
             return [True] * len(sets)
 
-    service = VerificationService(Slowish(), target_batch=1, pipeline=False)
+    service = VerificationService(Slowish(), target_batch=1)
     futs = []
     for _ in range(40):          # keep the dispatcher saturated
         futs.extend(service.submit([mk()]) for _ in range(25))

@@ -15,9 +15,7 @@ host fallback and half-open probe exist for — and reports, per point:
 The device backend is a latency-shaped stub wired through the SAME
 failpoint the real kernel launch hits (crypto/tpu/bls.execute_chunk), so
 the sweep measures the recovery machinery, not BLS math, and runs in
-seconds.  `bench.py config_verify_service` records one point of this
-sweep into BENCH_PRIMARY.json (`goodput_under_faults`,
-`breaker_recovery_seconds`).
+seconds.
 
 `--remote` switches to the remote verification fabric: the same offered-
 load sweep against a SIMULATED verifier pool (in-process transport,

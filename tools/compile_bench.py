@@ -19,9 +19,7 @@ Usage:
                                   [--json out.json]
 
 The cached measurement runs in a subprocess (``--load-only`` mode) so it
-is an honest second-process start, not an in-process re-load.  bench.py
-drives this module to record the numbers into BENCH_WARM.json and the
-``warm_start_speedup`` key of BENCH_PRIMARY.json.
+is an honest second-process start, not an in-process re-load.
 """
 
 import argparse

@@ -15,9 +15,7 @@ Usage:
   python tools/lint.py --prune-waivers          # report stale entries
   python tools/lint.py --prune-waivers --apply  # and delete them
 
-Wired into tier-1 (tests/test_analysis.py runs this over the repo) and
-the bench.py preflight (a discipline regression fails the bench before
-it burns an hour of kernel time).
+Wired into tier-1 (tests/test_analysis.py runs this over the repo).
 """
 
 import argparse
